@@ -40,10 +40,8 @@ def n_active_params(arch: str) -> float:
     specs = registry.param_specs(cfg)
     import jax
 
-    from repro.compat import tree_leaves_with_path
-
     total = 0.0
-    for path, leaf in tree_leaves_with_path(specs):
+    for path, leaf in jax.tree.leaves_with_path(specs):
         name = jax.tree_util.keystr(path)
         size = math.prod(leaf.shape)
         if "embed" in name and "lm_head" not in name:

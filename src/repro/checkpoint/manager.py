@@ -30,13 +30,11 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from repro.compat import tree_leaves_with_path
-
 
 def _flatten_with_names(tree):
     leaves, treedef = jax.tree.flatten(tree)
     paths = [jax.tree_util.keystr(p)
-             for p, _ in tree_leaves_with_path(tree)]
+             for p, _ in jax.tree.leaves_with_path(tree)]
     return leaves, paths, treedef
 
 

@@ -118,11 +118,8 @@ jax.tree_util.register_dataclass(
     meta_fields=[])
 
 
-def run_vectorized(state: VecState, max_rounds: int = 1_000_000
-                   ) -> Tuple[VecState, int]:
-    """Run rounds until no vtask is runnable.  Uses a compiled while loop
-    (whole simulation stays on device — zero Python per round)."""
-
+@jax.jit
+def _run_rounds(state: VecState, max_rounds):
     def cond(carry):
         st, i = carry
         return jnp.any(st.runnable) & (i < max_rounds)
@@ -131,7 +128,16 @@ def run_vectorized(state: VecState, max_rounds: int = 1_000_000
         st, i = carry
         return _round(st), i + 1
 
-    st, rounds = jax.lax.while_loop(cond, body, (state, jnp.int32(0)))
+    return jax.lax.while_loop(cond, body, (state, jnp.int32(0)))
+
+
+def run_vectorized(state: VecState, max_rounds: int = 1_000_000
+                   ) -> Tuple[VecState, int]:
+    """Run rounds until no vtask is runnable.  Uses a compiled while loop
+    (whole simulation stays on device — zero Python per round), jitted
+    once per state shape: an unjitted loop would be traced and compiled
+    again on every call."""
+    st, rounds = _run_rounds(state, max_rounds)
     return st, int(rounds)
 
 

@@ -9,8 +9,9 @@ queuing — the hub's common-path latency control as one vectorized pass:
 
 The FIFO recurrence is a segmented max-plus scan (elements (S, A) with
 composition (max(S1, S2-A1), A1+A2)); within a VMEM tile it runs as a
-log-depth doubling on VREGs, and the running prefix + link id carry
-across tiles in VMEM/SMEM scratch (grid ``arbitrary``).
+log-depth doubling over one (1, block) lane row — shifts are
+``pltpu.roll`` plus a lane mask — and the running prefix + link id carry
+across tiles in (1, 1) VMEM scratch (grid ``arbitrary``).
 
 Messages must be pre-sorted by (link_id, send_vtime) — the hub batches
 per flush epoch, so the sort amortizes.  Oracle:
@@ -26,53 +27,56 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 NEG = -(2**30)  # python int: jnp scalars would be captured as consts
+_LOW = -(2**31)  # below every int32 value: identity of a lane max
 
 
-def _kernel(send_ref, ser_ref, link_ref, lat_ref, out_ref, carry_ref, *,
-            block):
-    j = pl.program_id(0)
+def _last_lane(x, lane, block):
+    """(1, 1) value of the row's last lane (a masked lane max: Mosaic
+    has no dynamic_slice to extract it directly)."""
+    return jnp.max(jnp.where(lane == block - 1, x, _LOW), axis=1,
+                   keepdims=True)
 
-    @pl.when(j == 0)
+
+def _kernel(send_ref, ser_ref, link_ref, lat_ref, out_ref,
+            s_run, a_run, last_link, *, block):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        carry_ref[0] = NEG          # S_run
-        carry_ref[1] = 0            # A_run
-        carry_ref[2] = -1           # last link id
+        s_run[...] = jnp.full_like(s_run, NEG)
+        a_run[...] = jnp.zeros_like(a_run)
+        last_link[...] = jnp.full_like(last_link, -1)
 
-    send = send_ref[...]
-    ser = ser_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     link = link_ref[...]
-    lat = lat_ref[...]
+    prev_link = jnp.where(lane == 0, last_link[...],
+                          pltpu.roll(link, 1, 1))
+    # segment-start flags as int32: the doubling rolls them with S and A
+    S, A = send_ref[...], ser_ref[...]
+    G = (link != prev_link).astype(jnp.int32)
 
-    prev_link = jnp.concatenate(
-        [jnp.full((1,), carry_ref[2], jnp.int32), link[:-1]])
-    seg_first = link != prev_link
-
-    # in-tile segmented max-plus scan via doubling
-    S, A, G = send, ser, seg_first
-    steps = int(math.log2(block))
-    for st in range(steps):
+    # in-tile segmented max-plus scan via doubling; lanes below the
+    # shift take the monoid identity (NEG, 0, no boundary), so tile-start
+    # prefixes compose with a no-op rather than a fake boundary
+    for st in range(int(math.log2(block))):
         d = 1 << st
-        # fills are the monoid identity (NEG, 0, False) so tile-start
-        # prefixes compose with a no-op rather than a fake boundary
-        S_sh = jnp.concatenate([jnp.full((d,), NEG, jnp.int32), S[:-d]])
-        A_sh = jnp.concatenate([jnp.zeros((d,), jnp.int32), A[:-d]])
-        G_sh = jnp.concatenate([jnp.zeros((d,), bool), G[:-d]])
-        S_new = jnp.where(G, S, jnp.maximum(S_sh, S - A_sh))
-        A_new = jnp.where(G, A, A_sh + A)
+        has = lane >= d
+        S_sh = jnp.where(has, pltpu.roll(S, d, 1), NEG)
+        A_sh = jnp.where(has, pltpu.roll(A, d, 1), 0)
+        G_sh = jnp.where(has, pltpu.roll(G, d, 1), 0)
+        first = G != 0
+        S_new = jnp.where(first, S, jnp.maximum(S_sh, S - A_sh))
+        A_new = jnp.where(first, A, A_sh + A)
         S, A, G = S_new, A_new, G | G_sh
 
     # fold the cross-tile carry into prefixes with no boundary yet
-    S_c, A_c = carry_ref[0], carry_ref[1]
-    S_fin = jnp.where(G, S, jnp.maximum(S_c, S - A_c))
-    A_fin = jnp.where(G, A, A_c + A)
-    out_ref[...] = S_fin + A_fin + lat
+    first = G != 0
+    S_fin = jnp.where(first, S, jnp.maximum(s_run[...], S - a_run[...]))
+    A_fin = jnp.where(first, A, a_run[...] + A)
+    out_ref[...] = S_fin + A_fin + lat_ref[...]
 
-    carry_ref[0] = S_fin[-1]
-    carry_ref[1] = A_fin[-1]
-    carry_ref[2] = link[-1]
+    s_run[...] = _last_lane(S_fin, lane, block)
+    a_run[...] = _last_lane(A_fin, lane, block)
+    last_link[...] = _last_lane(link, lane, block)
 
 
 def hub_route(send_vtime, size_bytes, link_id, link_bw_Bps, link_lat_ns,
@@ -92,7 +96,8 @@ def hub_route(send_vtime, size_bytes, link_id, link_bw_Bps, link_lat_ns,
         ser = (size_bytes.astype(jnp.float32) * 1e9
                / link_bw_Bps[link_id]).astype(jnp.int32)
     lat = link_lat_ns[link_id].astype(jnp.int32)
-    block = min(block, 1 << int(math.ceil(math.log2(max(m, 1)))))
+    # a lane row of at least 128: Mosaic rolls whole vregs
+    block = min(block, max(128, 1 << int(math.ceil(math.log2(max(m, 1))))))
     assert block & (block - 1) == 0
     m_pad = pl.cdiv(m, block) * block
     if m_pad != m:
@@ -103,20 +108,16 @@ def hub_route(send_vtime, size_bytes, link_id, link_bw_Bps, link_lat_ns,
         link_id = jnp.pad(link_id, pad, constant_values=2**30)
         lat = jnp.pad(lat, pad)
 
+    row = pl.BlockSpec((1, block), lambda j: (0, j))
     out = pl.pallas_call(
         functools.partial(_kernel, block=block),
         grid=(m_pad // block,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda j: (j,)),
-            pl.BlockSpec((block,), lambda j: (j,)),
-            pl.BlockSpec((block,), lambda j: (j,)),
-            pl.BlockSpec((block,), lambda j: (j,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((m_pad,), jnp.int32),
-        scratch_shapes=[pltpu.SMEM((3,), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        in_specs=[row, row, row, row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((1, m_pad), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, 1), jnp.int32)] * 3,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(send_vtime, ser, link_id, lat)
-    return out[:m]
+    )(*(x.reshape(1, m_pad) for x in (send_vtime, ser, link_id, lat)))
+    return out[0, :m]

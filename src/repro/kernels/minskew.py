@@ -6,62 +6,63 @@ Per dispatch round the scheduler computes (paper §3.2):
 
 At cluster scale (10^4..10^5 vtasks x 10^2..10^3 scopes) this is the
 per-round bottleneck — a masked segmented-min plus a masked all-reduce
-over the scope axis.  The kernel tiles the (N x S) membership matrix into
-VMEM blocks: grid (n_blocks, s_blocks) with the scope-min pass
-accumulating into a VMEM scratch row per scope block, then a second
-fused pass producing the per-vtask eligibility conjunction.
+over the scope axis.  Two passes tile the (N x S) membership matrix into
+VMEM blocks.  The minima pass runs grid (s_blocks, n_blocks) with the N
+reduction innermost, so each (1, bs) output block is initialised,
+accumulated and written back before the next one is visited.  The
+eligibility pass runs grid (n_blocks, s_blocks) with the S reduction
+innermost, accumulating into its resident (bn, 1) output block.
 
 Layout notes: vtimes are int32 ticks (see engine_jax); membership is a
-dense int8 mask (bitpacking is a further 8x but int8 keeps the VPU mask
-ops trivial); tiles are (8..512, 128)-aligned for the (8,128) VREG shape.
+dense int8 mask in HBM, widened to int32 per block in VMEM before it is
+compared.  Every operand is 2-D: per-vtask vectors are (N, 1)
+columns and per-scope vectors (1, S) rows, so the only broadcasts in
+the kernels are lane and sublane broadcasts of int32 values.  Blocks
+are (512, 128) at fleet shapes and the full extent when a dimension is
+smaller than one block.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 INF = 2**30  # python int: jnp scalars would be captured as consts
+_NEVER = 2**31 - 1   # threshold of a scope that gates nobody
 
 
-def _minima_kernel(vtime_ref, runnable_ref, member_ref, min_ref):
-    i = pl.program_id(0)
+def _member(member_ref):
+    # widen before comparing: an int8 compare yields a packed mask that
+    # Mosaic cannot relayout against the broadcast int32 operand
+    return member_ref[...].astype(jnp.int32) != 0
 
-    @pl.when(i == 0)
+
+def _minima_kernel(vr_ref, member_ref, min_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         min_ref[...] = jnp.full_like(min_ref, INF)
 
-    v = vtime_ref[...]                       # (bn,)
-    r = runnable_ref[...] != 0               # (bn,)
-    m = member_ref[...] != 0                 # (bn, bs)
-    vm = jnp.where(r[:, None] & m, v[:, None], INF)
-    min_ref[...] = jnp.minimum(min_ref[...], jnp.min(vm, axis=0))
+    vm = jnp.where(_member(member_ref), vr_ref[...], INF)   # (bn, bs)
+    min_ref[...] = jnp.minimum(min_ref[...],
+                               jnp.min(vm, axis=0, keepdims=True))
 
 
-def _elig_kernel(vtime_ref, runnable_ref, member_ref, skew_ref, minima_ref,
-                 elig_ref, ok_ref, *, ns):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+def _elig_kernel(vtime_ref, member_ref, thr_ref, ok_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         ok_ref[...] = jnp.ones_like(ok_ref)
 
-    v = vtime_ref[...]
-    m = member_ref[...] != 0
-    mins = minima_ref[...]
-    skew = skew_ref[...]
-    ok_scope = (v[:, None] <= mins[None, :] + skew[None, :])
-    ok_scope |= ~m | (mins == INF)[None, :]
-    ok_ref[...] &= jnp.all(ok_scope, axis=1).astype(jnp.int8)
+    within = (vtime_ref[...] <= thr_ref[...]).astype(jnp.int32)
+    ok = jnp.where(_member(member_ref), within, 1)           # (bn, bs)
+    ok_ref[...] = jnp.minimum(ok_ref[...],
+                              jnp.min(ok, axis=1, keepdims=True))
 
-    @pl.when(j == ns - 1)
-    def _finalize():
-        elig_ref[...] = ok_ref[...] & runnable_ref[...]
+
+def _block(dim: int, block: int) -> int:
+    """One block spanning the whole dimension when it fits, else
+    ``block`` (the dimension is then padded to a multiple of it)."""
+    return dim if dim <= block else block
 
 
 def minskew(vtime, runnable, membership, skew, *, block_n=512,
@@ -69,49 +70,48 @@ def minskew(vtime, runnable, membership, skew, *, block_n=512,
     """Returns (scope minima (S,), eligibility (N,) int8).
 
     vtime (N,) int32; runnable (N,) int8; membership (N, S) int8;
-    skew (S,) int32."""
+    skew (S,) int32.  Natively, ``block_n`` must be a multiple of 32
+    (int8 sublane tiling) and ``block_s`` of 128."""
     n, s = membership.shape
-    block_n = min(block_n, max(8, n))
-    block_s = min(block_s, max(8, s))
-    n_pad = pl.cdiv(n, block_n) * block_n
-    s_pad = pl.cdiv(s, block_s) * block_s
-    vtime = jnp.pad(vtime, (0, n_pad - n), constant_values=INF)
-    runnable = jnp.pad(runnable, (0, n_pad - n))
+    bn, bs = _block(n, block_n), _block(s, block_s)
+    n_pad = pl.cdiv(n, bn) * bn
+    s_pad = pl.cdiv(s, bs) * bs
+    nb, sb = n_pad // bn, s_pad // bs
+    live = runnable != 0
+    # padded rows and columns are non-members, so they never matter
     membership = jnp.pad(membership, ((0, n_pad - n), (0, s_pad - s)))
-    skew = jnp.pad(skew, (0, s_pad - s))
-    nb, sb = n_pad // block_n, s_pad // block_s
+    vr = jnp.pad(jnp.where(live, vtime, INF), (0, n_pad - n),
+                 constant_values=INF).reshape(n_pad, 1)
+    col = pl.BlockSpec((bn, 1), lambda j, i: (i, 0))
 
     minima = pl.pallas_call(
         _minima_kernel,
-        grid=(nb, sb),
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n, block_s), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((block_s,), lambda i, j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((s_pad,), jnp.int32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "parallel")),
-        interpret=interpret,
-    )(vtime, runnable, membership)
-
-    elig = pl.pallas_call(
-        functools.partial(_elig_kernel, ns=sb),
-        grid=(nb, sb),
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n, block_s), lambda i, j: (i, j)),
-            pl.BlockSpec((block_s,), lambda i, j: (j,)),
-            pl.BlockSpec((block_s,), lambda i, j: (j,)),
-        ],
-        out_specs=pl.BlockSpec((block_n,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int8),
-        scratch_shapes=[pltpu.VMEM((block_n,), jnp.int8)],
-        compiler_params=tpu_compiler_params(
+        grid=(sb, nb),
+        in_specs=[col, pl.BlockSpec((bn, bs), lambda j, i: (i, j))],
+        out_specs=pl.BlockSpec((1, bs), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, s_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(vtime, runnable, membership, skew, minima)
+    )(vr, membership)
 
-    return minima[:s], elig[:n]
+    skew = jnp.pad(skew, (0, s_pad - s)).reshape(1, s_pad)
+    thr = jnp.where(minima == INF, _NEVER, minima + skew)
+    v = jnp.pad(vtime, (0, n_pad - n)).reshape(n_pad, 1)
+    ok = pl.pallas_call(
+        _elig_kernel,
+        grid=(nb, sb),
+        in_specs=[
+            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bn, bs), lambda i, j: (i, j)),
+            pl.BlockSpec((1, bs), lambda i, j: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(v, membership, thr)
+
+    elig = (ok[:n, 0] != 0) & live
+    return minima[0, :s], elig.astype(jnp.int8)

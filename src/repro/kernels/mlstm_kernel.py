@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 I_CAP = 8.0
 
 
@@ -101,7 +99,7 @@ def mlstm_chunkwise(q, k, v, i_raw, f_raw, *, chunk=128, interpret=False):
             pltpu.VMEM((hd, hd), jnp.float32),
             pltpu.VMEM((hd,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, i_raw, f_raw)

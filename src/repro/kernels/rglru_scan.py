@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 
 def _kernel(log_a_ref, b_ref, h0_ref, out_ref, carry_ref, *,
             block_t, n_t):
@@ -82,7 +80,7 @@ def rglru_scan(log_a, b, h0=None, *, block_t=256, interpret=False):
         out_specs=pl.BlockSpec((1, block_t, w), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, s_pad, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((w,), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b, h0)

@@ -10,14 +10,21 @@ from __future__ import annotations
 import jax
 
 
+def _mesh(shape, axes):
+    # Auto axes: the model code places arrays with with_sharding_constraint,
+    # which only accepts Auto axes (make_mesh defaults to Explicit).
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 1, model: int = 1, pod: int = 0):
     """Small mesh for CPU tests (axis sizes 1 keep collectives trivial)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))

@@ -72,22 +72,27 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.cmd == "record":
+        from repro import configs
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
+        cfg = configs.get_smoke(args.arch)
         if args.scenario == "recovery":
-            from repro.sim.live import record_live_recovery
+            from repro.sim.live import TrainerStack, record_live_recovery
             report, ledger = record_live_recovery(
-                args.out, arch=args.arch, engine=args.engine,
-                calibration=args.calibration, n_steps=args.n_steps,
+                args.out, stack=TrainerStack(cfg=cfg, n_steps=args.n_steps),
+                engine=args.engine, calibration=args.calibration,
+                n_steps=args.n_steps,
                 checkpoint_every=args.checkpoint_every)
         elif args.scenario == "serve":
-            from repro.sim.live import record_live_serve
+            from repro.sim.live import ServeStack, record_live_serve
             report, ledger = record_live_serve(
-                args.out, arch=args.arch, engine=args.engine,
-                calibration=args.calibration,
+                args.out, stack=ServeStack(cfg=cfg),
+                engine=args.engine, calibration=args.calibration,
                 n_requests=args.n_requests)
         else:
             from repro.sim.live import record_live_colocated
             report, ledger = record_live_colocated(
-                args.out, arch=args.arch, engine=args.engine,
+                args.out, cfg=cfg, engine=args.engine,
                 calibration=args.calibration)
         print(f"recorded {args.scenario} -> {args.out} "
               f"({sum(len(v) for v in ledger.tasks.values())} costs)")

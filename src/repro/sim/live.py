@@ -192,14 +192,19 @@ class TrainerStack:
     """Record-mode binding of the seed's real runtime/checkpoint layers
     to the live recovery driver's phases.  All JAX imports are lazy so
     the module stays importable from forked dist workers (which never
-    touch this class — replay mode passes ``stack=None``)."""
+    touch this class — replay mode passes ``stack=None``).
 
-    def __init__(self, *, arch: str = "qwen3_4b", n_steps: int = 8,
+    ``cfg`` is the model to train (tests pass a smoke config, the chip
+    smoke run the published widths at a cut depth).  ``mesh_shape`` and
+    ``remesh_shape`` are (data, model) meshes before and after the
+    failure; each must fit the devices present."""
+
+    def __init__(self, *, cfg, n_steps: int = 8,
                  seq_len: int = 32, global_batch: int = 4,
                  mesh_shape: Sequence[int] = (2, 1),
                  remesh_shape: Sequence[int] = (1, 1),
                  checkpoint_dir: Optional[str] = None, seed: int = 0):
-        self.arch = arch
+        self.cfg = cfg
         self.n_steps = n_steps
         self.seq_len = seq_len
         self.global_batch = global_batch
@@ -209,15 +214,18 @@ class TrainerStack:
         self.seed = seed
         self.trainer = None
         self.params = self.opt = None
+        self.history: List[tuple] = []   # (step, loss) per step() call
         self._ctx = contextlib.ExitStack()
 
     def _mesh(self, shape):
         import jax
         from repro.launch.mesh import make_test_mesh
-        data, model = shape
+        data, model = (int(x) for x in shape)
         ndev = len(jax.devices())
-        data = max(1, min(int(data), ndev // max(1, int(model))))
-        return make_test_mesh(data=data, model=int(model))
+        if data * model > ndev:
+            raise ValueError(f"mesh (data={data}, model={model}) needs "
+                             f"{data * model} devices, {ndev} present")
+        return make_test_mesh(data=data, model=model)
 
     def setup(self) -> None:
         if self.trainer is not None:
@@ -225,13 +233,12 @@ class TrainerStack:
         import dataclasses
         import tempfile
 
+        import jax
         import jax.numpy as jnp
 
-        from repro import configs
         from repro.parallel import ctx as pctx
         from repro.runtime.trainer import Trainer, TrainerConfig
-        cfg = dataclasses.replace(configs.get_smoke(self.arch),
-                                  remat=False)
+        cfg = dataclasses.replace(self.cfg, remat=False)
         ckpt_dir = self.checkpoint_dir or tempfile.mkdtemp(
             prefix="repro_live_ckpt_")
         tcfg = TrainerConfig(
@@ -248,17 +255,20 @@ class TrainerStack:
         self.params, self.opt = self.trainer.init_state()
         # warm the jit so recorded step costs are steady-state, not
         # compile time (an unrecorded step 0 on synthetic data)
-        self.params, self.opt, _ = self.trainer.step(
+        self.params, self.opt, metrics = self.trainer.step(
             self.params, self.opt, jnp.int32(0),
             self.trainer.data.batch(0))
+        # dispatch is async: without the wait, the first recorded step
+        # would also pay for the warm-up step's execution
+        jax.block_until_ready(metrics["loss"])
 
     def step(self, step: int) -> None:
-        import jax
         import jax.numpy as jnp
         self.params, self.opt, metrics = self.trainer.step(
             self.params, self.opt, jnp.int32(step),
             self.trainer.data.batch(step))
-        jax.block_until_ready(metrics["loss"])
+        # float() waits for the step, so its wall span is the execution
+        self.history.append((step, float(metrics["loss"])))
 
     def save(self, step: int) -> None:
         self.trainer.ckpt.save({"params": self.params, "opt": self.opt},
@@ -592,13 +602,20 @@ def live_recovery_sim(ledger: CostLedger, *,
         placement=wl.default_placement())
 
 
-def record_live_recovery(out_path, *, arch: str = "qwen3_4b",
-                         seq_len: int = 32, global_batch: int = 4,
+def _smoke_config():
+    """The model the canned recorders run when given no stack."""
+    from repro import configs
+    return configs.get_smoke("qwen3_4b")
+
+
+def record_live_recovery(out_path, *,
+                         stack: Optional[TrainerStack] = None,
                          calibration: float = 1.0,
                          engine: str = "async", **overrides):
     """One-shot recorder for the canned recovery scenario: run the real
     sharded trainer under simulated time, measure every phase, and save
-    the trace to ``out_path``.  Returns ``(report, ledger)``.
+    the trace to ``out_path``.  Returns ``(report, ledger)``.  ``stack``
+    defaults to the smoke model on a (2, 1) mesh re-meshed to (1, 1).
 
     The failure vtime (unless overridden) is placed from a probe step:
     a little past the first checkpoint commit, so the restore resumes
@@ -607,8 +624,12 @@ def record_live_recovery(out_path, *, arch: str = "qwen3_4b",
     ledger = CostLedger.record(calibration=calibration)
     params = dict(RECOVERY_DEFAULTS)
     params.update(overrides)
-    stack = TrainerStack(arch=arch, n_steps=params["n_steps"],
-                         seq_len=seq_len, global_batch=global_batch)
+    if stack is None:
+        stack = TrainerStack(cfg=_smoke_config(),
+                             n_steps=params["n_steps"])
+    elif stack.n_steps != params["n_steps"]:
+        raise ValueError(f"stack trains {stack.n_steps} steps, the "
+                         f"scenario {params['n_steps']}")
     stack.setup()
     if "fail_at_vtime" not in overrides:
         t0 = _time.perf_counter_ns()
@@ -656,15 +677,16 @@ class ServeStack:
     actually carries, so one compiled program serves every wave and
     recorded costs reflect the static batch the real server would
     execute.  Prompts are deterministic functions of the wave index —
-    no RNG stream in the record path."""
+    no RNG stream in the record path.  ``cfg`` is the served model
+    (tests pass a smoke config, the chip smoke run the published one)."""
 
-    def __init__(self, *, arch: str = "qwen3_4b", max_batch: int = 4,
+    def __init__(self, *, cfg, max_batch: int = 4,
                  prompt_len: int = 8, decode_steps: int = 4,
                  seed: int = 0):
         if max_batch < 1 or prompt_len < 1 or decode_steps < 1:
             raise ValueError("max_batch, prompt_len and decode_steps "
                              "must be >= 1")
-        self.arch = arch
+        self.cfg = cfg
         self.max_batch = max_batch
         self.prompt_len = prompt_len
         self.decode_steps = decode_steps
@@ -689,12 +711,13 @@ class ServeStack:
         import jax
         import jax.numpy as jnp
 
-        from repro import configs
         from repro.models import registry
         from repro.serve.loop import BatchServer
-        cfg = dataclasses.replace(configs.get_smoke(self.arch),
-                                  remat=False)
-        params = registry.init(cfg, jax.random.PRNGKey(self.seed))
+        cfg = dataclasses.replace(self.cfg, remat=False)
+        # jitted, so random f32 draws fuse into the bf16 weights instead
+        # of materializing (a full-size model would not fit twice)
+        params = jax.jit(registry.init, static_argnums=0)(
+            cfg, jax.random.PRNGKey(self.seed))
         self.server = BatchServer(cfg, params,
                                   max_new_tokens=self.decode_steps + 1)
         # warm both jits so recorded per-wave costs are steady-state
@@ -776,13 +799,15 @@ def live_serve_sim(ledger: CostLedger, *,
     return Simulation(topo, wl, placement=wl.default_placement())
 
 
-def record_live_serve(out_path, *, arch: str = "qwen3_4b",
-                      prompt_len: int = 8, calibration: float = 1.0,
+def record_live_serve(out_path, *, stack: Optional[ServeStack] = None,
+                      calibration: float = 1.0,
                       engine: str = "async", **overrides):
     """One-shot recorder for the canned serve scenario: run the real
     BatchServer under simulated time against an open-loop Poisson
     schedule, measure every wave phase, and save the trace to
-    ``out_path``.  Returns ``(report, ledger)``.
+    ``out_path``.  Returns ``(report, ledger)``.  ``stack`` defaults to
+    the smoke model with 8-token prompts; a given stack must match the
+    scenario's ``max_batch`` and ``decode_steps``.
 
     Unless ``arrivals``/``mean_gap_ns`` is overridden, the schedule is
     derived from a probe wave (one prefill + ``decode_steps`` decodes):
@@ -794,9 +819,17 @@ def record_live_serve(out_path, *, arch: str = "qwen3_4b",
     ledger = CostLedger.record(calibration=calibration)
     params = dict(SERVE_DEFAULTS)
     params.update(overrides)
-    stack = ServeStack(arch=arch, max_batch=params["max_batch"],
-                       prompt_len=prompt_len,
-                       decode_steps=params["decode_steps"])
+    if stack is None:
+        stack = ServeStack(cfg=_smoke_config(),
+                           max_batch=params["max_batch"],
+                           decode_steps=params["decode_steps"])
+    elif (stack.max_batch, stack.decode_steps) != (
+            params["max_batch"], params["decode_steps"]):
+        raise ValueError(
+            f"stack serves max_batch={stack.max_batch}, decode_steps="
+            f"{stack.decode_steps}; the scenario max_batch="
+            f"{params['max_batch']}, decode_steps="
+            f"{params['decode_steps']}")
     stack.setup()
     if params["arrivals"] is None and params["mean_gap_ns"] is None:
         t0 = _time.perf_counter_ns()
@@ -903,15 +936,15 @@ def live_colocated_sim(ledger: CostLedger, *,
     return Simulation(topo, [train, serve], placement=placement)
 
 
-def record_live_colocated(out_path, *, arch: str = "qwen3_4b",
-                          seq_len: int = 32, global_batch: int = 4,
-                          prompt_len: int = 8,
+def record_live_colocated(out_path, *, cfg=None, seq_len: int = 32,
+                          global_batch: int = 4, prompt_len: int = 8,
                           calibration: float = 1.0,
                           engine: str = "async", **overrides):
     """One-shot recorder for the co-located scenario: real trainer
     steps (single-device mesh, in-process) interleaved with real
     BatchServer waves, both measured into one multi-driver ledger under
-    the in-process engines' one-live-call-at-a-time dispatch.  Returns
+    the in-process engines' one-live-call-at-a-time dispatch.  ``cfg``
+    (a ModelConfig) defaults to the smoke model.  Returns
     ``(report, ledger)``."""
     import time as _time
     ledger = CostLedger.record(calibration=calibration)
@@ -921,11 +954,12 @@ def record_live_colocated(out_path, *, arch: str = "qwen3_4b",
             raise ValueError(f"unknown colocated section {k!r}")
         params[k].update(v)
     tp, sp = params["train"], params["serve"]
-    train_stack = TrainerStack(arch=arch, n_steps=tp["n_steps"],
+    cfg = cfg or _smoke_config()
+    train_stack = TrainerStack(cfg=cfg, n_steps=tp["n_steps"],
                                seq_len=seq_len,
                                global_batch=global_batch,
                                mesh_shape=(1, 1))
-    serve_stack = ServeStack(arch=arch, max_batch=sp["max_batch"],
+    serve_stack = ServeStack(cfg=cfg, max_batch=sp["max_batch"],
                              prompt_len=prompt_len,
                              decode_steps=sp["decode_steps"])
     train_stack.setup()

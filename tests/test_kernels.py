@@ -163,6 +163,7 @@ def test_mlstm_matches_model_chunkwise():
         (200, 40, 64, 16),              # padded both dims
         (512, 128, 512, 128),
         (1000, 3, 256, 8),
+        (1100, 300, 512, 128),          # native blocks, 3x3 grid, padded
     ])
 def test_minskew_vs_ref(n, s, bn, bs):
     vtime = jnp.asarray(RNG.integers(0, 10_000, n), jnp.int32)
@@ -311,7 +312,8 @@ def test_minskew_tiny_shapes():
 
 
 @pytest.mark.parametrize("m,block", [(1, 64), (7, 64), (129, 64),
-                                     (500, 128)])
+                                     (500, 128),
+                                     (5000, 1024)])   # 5 tiles, carries
 def test_hub_route_ser_ns_bitexact(m, block):
     """With integer ``ser_ns`` the kernel must match the sequential
     oracle *bit-exactly* — no float32 serialization slop.  This is the

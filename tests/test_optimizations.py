@@ -1,5 +1,6 @@
 """Beyond-paper optimizations must be numerically exact vs baseline."""
 import dataclasses
+import pathlib
 import subprocess
 import sys
 
@@ -32,7 +33,8 @@ for arch in ("phi3_medium_14b", "qwen3_4b", "glm4_9b"):
 print("TP_OK")
 """
     env = {**__import__("os").environ, "PYTHONPATH": "src"}
-    res = subprocess.run([sys.executable, "-c", prog], cwd="/root/repo",
+    res = subprocess.run([sys.executable, "-c", prog],
+                         cwd=str(pathlib.Path(__file__).parent.parent),
                          env=env, capture_output=True, text=True,
                          timeout=600)
     assert "TP_OK" in res.stdout, res.stderr[-2000:]

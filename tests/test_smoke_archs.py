@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.compat import tree_leaves_with_path
 from repro.models import registry
 from repro.models.common import softmax_cross_entropy
 
@@ -73,15 +72,15 @@ def test_param_specs_match_init(arch, rng):
     cfg = configs.get_smoke(arch)
     params = registry.init(cfg, rng)
     specs = registry.param_specs(cfg)
-    flat_p = tree_leaves_with_path(params)
-    flat_s = tree_leaves_with_path(specs)
+    flat_p = jax.tree.leaves_with_path(params)
+    flat_s = jax.tree.leaves_with_path(specs)
     assert len(flat_p) == len(flat_s)
     for (kp, vp), (ks, vs) in zip(flat_p, flat_s):
         assert kp == ks
         assert vp.shape == vs.shape, f"{kp}: {vp.shape} != {vs.shape}"
         assert vp.dtype == vs.dtype, f"{kp}: {vp.dtype} != {vs.dtype}"
     axes = registry.logical_axes(cfg)
-    flat_a = tree_leaves_with_path(
+    flat_a = jax.tree.leaves_with_path(
         axes, is_leaf=lambda x: isinstance(x, tuple))
     assert len(flat_a) == len(flat_p)
     for (kp, vp), (ka, va) in zip(flat_p, flat_a):
