@@ -169,9 +169,9 @@ def phase_vectorized(seed: int) -> None:
     emit("vectorized", tape=list(comp.tape.op_kind.shape),
          messages=first.messages, scopes=comp.tape.membership.shape[1],
          vtime_ns=first.vtime_ns, rounds=first.sync_rounds,
-         first_run_s=first.wall_s, steady_run_s=steady.wall_s,
+         first_call_s=first.wall_s, steady_call_s=steady.wall_s,
          compile_s=first.wall_s - steady.wall_s,
-         pallas_off_first_run_s=off.wall_s, async_run_s=ref.wall_s,
+         pallas_off_first_call_s=off.wall_s, async_run_s=ref.wall_s,
          identical=["native", "off", "async"],
          kernels=kernels_vs_oracles(seed))
 
@@ -197,7 +197,7 @@ def phase_sweep(n_variants: int = 64, lanes=(0, 1, 37, 63)) -> None:
               and steady.reports[i].tasks == ref.tasks,
               f"sweep lane {i} differs from async")
     emit("sweep", variants=n_variants, lanes_checked=list(lanes),
-         first_wall_s=first.wall_s, steady_wall_s=steady.wall_s,
+         first_call_s=first.wall_s, steady_call_s=steady.wall_s,
          steady_configs_per_s=steady.configs_per_s,
          vtime_ns=sorted({r.vtime_ns for r in steady.reports}))
 

@@ -119,5 +119,6 @@ def hub_route(send_vtime, size_bytes, link_id, link_bw_Bps, link_lat_ns,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="hub_route",
     )(*(x.reshape(1, m_pad) for x in (send_vtime, ser, link_id, lat)))
     return out[0, :m]

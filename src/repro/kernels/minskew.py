@@ -93,6 +93,7 @@ def minskew(vtime, runnable, membership, skew, *, block_n=512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="minskew_minima",
     )(vr, membership)
 
     skew = jnp.pad(skew, (0, s_pad - s)).reshape(1, s_pad)
@@ -111,6 +112,7 @@ def minskew(vtime, runnable, membership, skew, *, block_n=512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="minskew_eligible",
     )(v, membership, thr)
 
     elig = (ok[:n, 0] != 0) & live

@@ -14,7 +14,11 @@ resolves the tension with two modes:
   clock ``calibration`` (the pvclock analogue: simulated-ns per
   host-ns), clamps to >= 1 ns, and appends ``{label, cost_ns}`` to the
   per-task trace.  One record run per scenario; the trace is saved as
-  versioned JSON (``live_trace/v1``).
+  versioned JSON (``live_trace/v1``).  The measured interval lies inside
+  a span ``live.<kind>`` (``repro.obs``; the kind is the label up to its
+  first ``:``, e.g. ``decode``), whose metadata are the full label and
+  the task, so a profiler trace shows what the device did in each
+  charged span.
 
   **Multi-driver recording** (SplitSim's isolation concern, PAPERS.md):
   one record run may capture several live drivers — e.g. a trainer and
@@ -56,6 +60,8 @@ import json
 import pathlib
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+
+from repro import obs
 
 TRACE_SCHEMA = "live_trace/v1"
 
@@ -150,10 +156,12 @@ class CostLedger:
                     f"threads")
             self._measuring = (task, label)
             try:
-                t0 = time.perf_counter_ns()
-                result = fn(*args, **(kwargs or {})) if fn is not None \
-                    else None
-                span = time.perf_counter_ns() - t0
+                with obs.span("live." + label.split(":", 1)[0],
+                              label=label, task=task):
+                    t0 = time.perf_counter_ns()
+                    result = fn(*args, **(kwargs or {})) \
+                        if fn is not None else None
+                    span = time.perf_counter_ns() - t0
             finally:
                 self._measuring = None
             # zero/negative spans (sub-ns callables, clock warp under a

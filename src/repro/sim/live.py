@@ -55,6 +55,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.ipc import LinkSpec
 from repro.core.vtask import Compute, LiveCall, Recv, Send
 from repro.live import CostLedger
@@ -665,6 +666,13 @@ def recovery_timeline(report, *, workload: str = "live_train",
 # ---------------------------------------------------------------------------
 
 
+#: spans of one serve step inside its charged interval: the jitted
+#: step's dispatch, the greedy sampling, the wait for the device
+_SERVE_STEP = obs.span("serve.step")
+_SERVE_SAMPLE = obs.span("serve.sample")
+_SERVE_SYNC = obs.span("serve.sync")
+
+
 class ServeStack:
     """Record-mode binding of the real :class:`~repro.serve.loop.
     BatchServer` to :class:`~repro.sim.workloads.LiveServe`'s per-wave
@@ -729,20 +737,25 @@ class ServeStack:
         jax.block_until_ready(logits)
 
     def prefill(self, wave: int, batch: int) -> None:
-        import jax
-        import jax.numpy as jnp
-        logits, self._cache = self.server._prefill(
-            self.server.params, self._prompts(wave), None)
-        self._tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(self._tok)
+        with _SERVE_STEP:
+            logits, self._cache = self.server._prefill(
+                self.server.params, self._prompts(wave), None)
+        self._sample(logits)
 
     def decode(self, wave: int, d: int) -> None:
+        with _SERVE_STEP:
+            logits, self._cache = self.server._decode(
+                self.server.params, self._tok, self._cache)
+        self._sample(logits)
+
+    def _sample(self, logits) -> None:
+        """Greedy next tokens, held until the device has them."""
         import jax
         import jax.numpy as jnp
-        logits, self._cache = self.server._decode(
-            self.server.params, self._tok, self._cache)
-        self._tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(self._tok)
+        with _SERVE_SAMPLE:
+            self._tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with _SERVE_SYNC:
+            jax.block_until_ready(self._tok)
 
     def close(self) -> None:
         self._tok = self._cache = None

@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import engine_jax as ej
 from repro.core.scheduler import Scheduler
 from repro.core.vtime import SEC
@@ -560,7 +561,10 @@ def compile_simulation(sim, tick_ns: Optional[int] = None) -> CompiledSim:
     :class:`UnsupportedByEngine` for inadmissible scenarios and
     :class:`~repro.core.engine_jax.TickRangeError` when the horizon
     bound exceeds the int32 tick range at the chosen tick."""
-    return _quantize(_lower(sim), tick_ns)
+    with obs.span("sim.lower"):
+        low = _lower(sim)
+    with obs.span("sim.quantize"):
+        return _quantize(low, tick_ns)
 
 
 # ---------------------------------------------------------------------------
@@ -607,23 +611,24 @@ def _batched_visibility(comp: CompiledSim, sent: np.ndarray,
     use_pallas = pallas in ("on", "interpret")
 
     def fanout(send, ser, link_id, lat_t):
-        if use_pallas:
-            from repro.kernels.hub_route import hub_route
-            out = hub_route(jnp.asarray(send, jnp.int32),
-                            jnp.asarray(ser, jnp.int32),
-                            jnp.asarray(link_id, jnp.int32),
-                            jnp.asarray(bw),
-                            jnp.asarray(lat_t, jnp.int32),
-                            ser_ns=jnp.asarray(ser, jnp.int32),
-                            interpret=pallas == "interpret")
-        else:
-            out = ej.hub_visibility(jnp.asarray(send, jnp.int32),
-                                    jnp.asarray(ser, jnp.int32),
-                                    jnp.asarray(link_id, jnp.int32),
-                                    jnp.asarray(bw),
-                                    jnp.asarray(lat_t, jnp.int32),
-                                    ser_ns=jnp.asarray(ser, jnp.int32))
-        return np.asarray(out, np.int64)
+        with obs.span("sim.fanout"):
+            if use_pallas:
+                from repro.kernels.hub_route import hub_route
+                out = hub_route(jnp.asarray(send, jnp.int32),
+                                jnp.asarray(ser, jnp.int32),
+                                jnp.asarray(link_id, jnp.int32),
+                                jnp.asarray(bw),
+                                jnp.asarray(lat_t, jnp.int32),
+                                ser_ns=jnp.asarray(ser, jnp.int32),
+                                interpret=pallas == "interpret")
+            else:
+                out = ej.hub_visibility(jnp.asarray(send, jnp.int32),
+                                        jnp.asarray(ser, jnp.int32),
+                                        jnp.asarray(link_id, jnp.int32),
+                                        jnp.asarray(bw),
+                                        jnp.asarray(lat_t, jnp.int32),
+                                        ser_ns=jnp.asarray(ser, jnp.int32))
+            return np.asarray(out, np.int64)
 
     # stage 1: all messages, per-channel program order (= array order
     # per channel; lexsort keeps it within each channel)
@@ -659,8 +664,10 @@ def _resolve_pallas(pallas: str) -> Tuple[bool, bool]:
     return pallas != "off", pallas == "interpret"
 
 
-def _decompile(sim, comp: CompiledSim, st, wall: float, *,
-               pallas: str, verify: bool) -> SimReport:
+def _decompile(sim, comp: CompiledSim, st, *, pallas: str,
+               verify: bool) -> SimReport:
+    """The report of one run's final state; its ``wall_s`` is left 0 for
+    the caller, which times the whole call."""
     tick = comp.tick_ns
     vtime = np.asarray(st.vtime, np.int64)
     pc = np.asarray(st.pc)
@@ -738,7 +745,7 @@ def _decompile(sim, comp: CompiledSim, st, wall: float, *,
     horizon = int(vtime.max(initial=0)) * tick
     return SimReport(
         status=status, mode="vectorized", n_hosts=comp.n_hosts,
-        vtime_ns=horizon, wall_s=wall, messages=msgs_total,
+        vtime_ns=horizon, wall_s=0.0, messages=msgs_total,
         bytes=bytes_total, sync_rounds=rounds, proxy_syncs=0,
         cross_host_msgs=cross, max_proxy_staleness_ns=0,
         max_window_ns=0, hosts=hosts, links=links, tasks=tasks,
@@ -751,25 +758,32 @@ def run_vectorized_sim(sim, *, tick_ns: Optional[int] = None,
                        max_rounds: Optional[int] = None,
                        verify: bool = False) -> SimReport:
     """Compile ``sim``, run the jitted round loop, decompile the
-    resulting arrays to a :class:`SimReport` (``mode="vectorized"``)."""
+    resulting arrays to a :class:`SimReport` (``mode="vectorized"``).
+    The report's ``wall_s`` is the whole call: lowering, the loop and
+    decompiling, and any compilation they trigger."""
     import jax
     use_pallas, interpret = _resolve_pallas(pallas)
-    t0 = time.perf_counter()
-    comp = compile_simulation(sim, tick_ns)
-    cap = comp.max_rounds if max_rounds is None else max_rounds
-    st0 = ej.init_vec_sim_state(comp.tape, comp.n_channels)
-    st = ej.run_vec_tape(comp.tape, st0, cap, pallas=use_pallas,
-                         interpret=interpret)
-    jax.block_until_ready(st.vtime)
-    wall = time.perf_counter() - t0
-    if bool(st.progressed) and not bool(np.asarray(st.done).all()):
-        raise RuntimeError(
-            f"vectorized engine: max_rounds={cap} exhausted before "
-            f"the fixpoint")
-    return _decompile(sim, comp, st, wall,
-                      pallas=("interpret" if interpret
-                              else "on" if use_pallas else "off"),
-                      verify=verify)
+    with obs.span("sim.run"):
+        t0 = time.perf_counter()
+        comp = compile_simulation(sim, tick_ns)
+        cap = comp.max_rounds if max_rounds is None else max_rounds
+        with obs.span("sim.stage"):
+            st0 = ej.init_vec_sim_state(comp.tape, comp.n_channels)
+        with obs.span("sim.loop"):
+            st = ej.run_vec_tape(comp.tape, st0, cap, pallas=use_pallas,
+                                 interpret=interpret)
+            jax.block_until_ready(st.vtime)
+        if bool(st.progressed) and not bool(np.asarray(st.done).all()):
+            raise RuntimeError(
+                f"vectorized engine: max_rounds={cap} exhausted before "
+                f"the fixpoint")
+        with obs.span("sim.decompile"):
+            report = _decompile(sim, comp, st,
+                                pallas=("interpret" if interpret
+                                        else "on" if use_pallas else "off"),
+                                verify=verify)
+        report.wall_s = time.perf_counter() - t0
+    return report
 
 
 # ---------------------------------------------------------------------------
@@ -779,7 +793,11 @@ def run_vectorized_sim(sim, *, tick_ns: Optional[int] = None,
 
 @dataclasses.dataclass
 class SweepResult:
-    """One compiled dispatch over V scenario variants."""
+    """One compiled dispatch over V scenario variants.  ``wall_s`` is the
+    whole :func:`sweep_vectorized` call (building, lowering and
+    quantizing the variants, the batched loop, decompiling every
+    report), ``configs_per_s`` is V over it, and each report's
+    ``wall_s`` is its V-th share."""
     reports: List[SimReport]
     wall_s: float
     configs_per_s: float
@@ -803,48 +821,64 @@ def sweep_vectorized(sim, axis: List[Scenario], *,
     if not axis:
         raise ValueError("sweep needs at least one Scenario")
     from repro.sim.simulation import Simulation
-    variants = [
-        Simulation(sim.topology, sim.workloads, sc,
-                   placement=sim.placement_spec, mode=sim.mode,
-                   capacity=sim.capacity, cpu_resource=sim.cpu_resource,
-                   cells=sim.cells_mode)
-        for sc in axis]
-    lows = [_lower(v) for v in variants]
-    if tick_ns is None:
-        pos = [q for low in lows for q in _quantities(low) if q > 0]
-        tick_ns = math.gcd(*pos) if pos else 1
-    comps = [_quantize(low, tick_ns) for low in lows]
-    base = comps[0]
-    shapes = [jax.tree_util.tree_map(lambda x: jnp.shape(x), c.tape)
-              for c in comps]
-    if any(sh != shapes[0] for sh in shapes[1:]):
-        raise UnsupportedByEngine(
-            "sweep variants must share scenario structure (same "
-            "tapes/messages/channels); only injection values may vary")
-    tapes = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                   *[c.tape for c in comps])
-    states = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs),
-        *[ej.init_vec_sim_state(c.tape, base.n_channels)
-          for c in comps])
-    cap = (max(c.max_rounds for c in comps)
-           if max_rounds is None else max_rounds)
-    t0 = time.perf_counter()
-    out = ej.run_vec_tape_batch(tapes, states, cap)
-    jax.block_until_ready(out.vtime)
-    wall = time.perf_counter() - t0
-    reports = []
-    for v, comp in enumerate(comps):
-        st_v = jax.tree_util.tree_map(lambda x: x[v], out)
-        if bool(st_v.progressed) and \
-                not bool(np.asarray(st_v.done).all()):
-            raise RuntimeError(
-                f"vectorized sweep variant {v}: max_rounds={cap} "
-                f"exhausted before the fixpoint")
-        reports.append(_decompile(variants[v], comp, st_v,
-                                  wall / len(comps), pallas="off",
-                                  verify=False))
+    with obs.span("sim.sweep"):
+        t0 = time.perf_counter()
+        with obs.span("sim.variants"):
+            variants = [
+                Simulation(sim.topology, sim.workloads, sc,
+                           placement=sim.placement_spec, mode=sim.mode,
+                           capacity=sim.capacity,
+                           cpu_resource=sim.cpu_resource,
+                           cells=sim.cells_mode)
+                for sc in axis]
+        lows = []
+        for v in variants:
+            with obs.span("sim.lower"):
+                lows.append(_lower(v))
+        if tick_ns is None:
+            with obs.span("sim.quantize"):
+                pos = [q for low in lows for q in _quantities(low) if q > 0]
+                tick_ns = math.gcd(*pos) if pos else 1
+        comps = []
+        for low in lows:
+            with obs.span("sim.quantize"):
+                comps.append(_quantize(low, tick_ns))
+        base = comps[0]
+        with obs.span("sim.stage"):
+            shapes = [jax.tree_util.tree_map(lambda x: jnp.shape(x), c.tape)
+                      for c in comps]
+            if any(sh != shapes[0] for sh in shapes[1:]):
+                raise UnsupportedByEngine(
+                    "sweep variants must share scenario structure (same "
+                    "tapes/messages/channels); only injection values may "
+                    "vary")
+            tapes = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                           *[c.tape for c in comps])
+            states = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs),
+                *[ej.init_vec_sim_state(c.tape, base.n_channels)
+                  for c in comps])
+        cap = (max(c.max_rounds for c in comps)
+               if max_rounds is None else max_rounds)
+        with obs.span("sim.loop"):
+            out = ej.run_vec_tape_batch(tapes, states, cap)
+            jax.block_until_ready(out.vtime)
+        reports = []
+        for v, comp in enumerate(comps):
+            with obs.span("sim.unstack"):
+                st_v = jax.tree_util.tree_map(lambda x: x[v], out)
+                if bool(st_v.progressed) and \
+                        not bool(np.asarray(st_v.done).all()):
+                    raise RuntimeError(
+                        f"vectorized sweep variant {v}: max_rounds={cap} "
+                        f"exhausted before the fixpoint")
+            with obs.span("sim.decompile"):
+                reports.append(_decompile(variants[v], comp, st_v,
+                                          pallas="off", verify=False))
+        wall = time.perf_counter() - t0
+    for report in reports:
+        report.wall_s = wall / len(reports)
     return SweepResult(reports=reports, wall_s=wall,
-                       configs_per_s=len(comps) / wall if wall > 0
+                       configs_per_s=len(reports) / wall if wall > 0
                        else float("inf"),
                        tick_ns=tick_ns, tier=base.tier)
