@@ -8,6 +8,11 @@ drop-ins validated against this module.
 Key property: queries are processed in chunks via ``lax.scan`` (native
 flash-style blocking at the HLO level), so a 32k×32k attention never
 materializes an (S, S) score tensor — per-chunk memory is (chunk, S).
+
+Grouped-query attention: prefill and training repeat each KV head to its
+query heads (``_repeat_kv``).  Single-token decode does not: it contracts
+each KV head with its group of query heads, so every step reads the KV
+cache once and writes no repeated copy of it (see ``decode_attention``).
 """
 from __future__ import annotations
 
@@ -168,14 +173,23 @@ def decode_attention(
     v_cache: jnp.ndarray,         # (B, S, Hkv, hd)
     cache_len: jnp.ndarray | int, # valid prefix length (scalar or (B,))
 ) -> jnp.ndarray:
-    """Single-token attention against a (possibly padded) KV cache."""
+    """Single-token attention against a (possibly padded) KV cache.
+
+    Each KV head is contracted with its group of ``G = H // Hkv`` query
+    heads (query head ``h`` belongs to KV head ``h // G``, the layout of
+    ``_repeat_kv``), so the cache is read once, in place.  Repeating the
+    cache to ``H`` heads first would write ``G`` copies of it every step,
+    and with one query position the per-head products lower to
+    elementwise multiply-reduces over that copy instead of matrix
+    products.  Same precision as the repeated form: operands in their
+    own dtype, scores and softmax in float32, probabilities cast to the
+    cache dtype for the product with V; for ``G = 1`` it is the same
+    contraction."""
     b, _, h, hd = q.shape
-    sk = k_cache.shape[1]
-    q_per_kv = h // k_cache.shape[2]
-    k = _repeat_kv(k_cache, q_per_kv)
-    v = _repeat_kv(v_cache, q_per_kv)
+    sk, hkv = k_cache.shape[1], k_cache.shape[2]
+    qg = q.reshape(b, hkv, h // hkv, hd)
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache).astype(jnp.float32) * scale
     kpos = jnp.arange(sk)
     cache_len = jnp.asarray(cache_len)
     if cache_len.ndim == 0:
@@ -184,4 +198,7 @@ def decode_attention(
         valid = kpos[None, :] < cache_len[:, None]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    # the (g, k) output order lowers P.V as the repeated form lowered it,
+    # so G = 1 gives the same bits; the TPU compiler folds the transpose
+    o = jnp.einsum("bkgs,bskd->bgkd", p.astype(v_cache.dtype), v_cache)
+    return o.transpose(0, 2, 1, 3).reshape(b, 1, h, hd)

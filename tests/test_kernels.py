@@ -80,6 +80,60 @@ def test_decode_attention_vs_ref(b, h, hkv, s, hd, bs, dtype):
         **TOL[dtype])
 
 
+def _decode_attention_repeated(q, k_cache, v_cache, cache_len):
+    """The GQA-repeat formulation ``models.attention.decode_attention``
+    had before it contracted per KV group: the oracle its output must
+    equal for G = 1 and match within rounding otherwise."""
+    from repro.models.attention import NEG_INF, _repeat_kv
+    b, _, h, hd = q.shape
+    sk = k_cache.shape[1]
+    k = _repeat_kv(k_cache, h // k_cache.shape[2])
+    v = _repeat_kv(v_cache, h // k_cache.shape[2])
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    s = s * (1.0 / jnp.sqrt(jnp.float32(hd)))
+    valid = jnp.arange(sk)[None, :] < jnp.reshape(cache_len, (-1, 1))
+    s = jnp.where(jnp.broadcast_to(valid, (b, sk))[:, None, None, :], s,
+                  NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("per_row_len", [False, True])
+@pytest.mark.parametrize("group", [1, 2, 4, 7, 16])
+def test_model_decode_attention_grouped(group, per_row_len, dtype):
+    """``models.attention.decode_attention`` against the float32
+    reference and the repeat formulation, on caches padded with large
+    garbage past the valid length."""
+    from repro.models.attention import decode_attention as model_decode
+    b, hkv, s, hd = 3, 2, 40, 32
+    h = hkv * group
+    q = rand((b, 1, h, hd), dtype)
+    k = np.asarray(RNG.standard_normal((b, s, hkv, hd)), np.float32)
+    v = np.asarray(RNG.standard_normal((b, s, hkv, hd)), np.float32)
+    lengths = (np.asarray([5, s - 3, 17], np.int32) if per_row_len
+               else np.full((b,), 23, np.int32))
+    for row, n in enumerate(lengths):
+        k[row, n:] = 50.0 * RNG.standard_normal((s - n, hkv, hd))
+        v[row, n:] = 1e3
+    k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+    cache_len = jnp.asarray(lengths) if per_row_len else int(lengths[0])
+
+    out = model_decode(q, k, v, cache_len)
+    assert out.shape == (b, 1, h, hd) and out.dtype == dtype
+    ref = kref.decode_attention_ref(q[:, 0], k, v, jnp.asarray(lengths))
+    np.testing.assert_allclose(np.asarray(out[:, 0], np.float32),
+                               np.asarray(ref, np.float32), **TOL[dtype])
+    old = _decode_attention_repeated(q, k, v, cache_len)
+    if group == 1:
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(old, np.float32))
+    else:
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(old, np.float32),
+                                   **TOL[dtype])
+
+
 # ---------------------------------------------------------------- rglru
 
 

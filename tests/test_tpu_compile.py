@@ -5,8 +5,9 @@ Interpret mode cannot show what the chip's compiler refuses: layouts it
 cannot relayout, primitives Mosaic does not lower, blocks that do not
 tile.  These tests compile the kernels of the main path at fleet shapes
 and the jitted round loop that calls them, and check that the kernels
-are in the program.  Nothing runs, so they say nothing about results or
-times (the oracle tests in ``test_kernels.py`` cover results).
+are in the program, and the live serve step's decode attention at the
+benchmark's serving shapes.  Nothing runs, so they say nothing about
+results or times (the oracle tests in ``test_kernels.py`` cover results).
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and every pytest
@@ -106,3 +107,19 @@ def test_round_loop_compiles_with_kernels(one_chip):
         jax.tree.map(spec, comp.tape), jax.tree.map(spec, st0),
         spec(jnp.int32(comp.max_rounds)), pallas=True).compile()
     assert _kernels_in(compiled) == 2
+
+
+def test_decode_attention_reads_the_cache_in_place(one_chip):
+    """Grouped-query decode at the qwen3-4b serving shapes (batch 8,
+    32 query / 8 KV heads of 128, cache 1281) contracts each KV head
+    with its query group: no copy of the cache repeated to 32 heads, and
+    the products are matrix products, not elementwise multiply-reduces."""
+    from repro.models.attention import decode_attention
+    cache = _spec(one_chip, (8, 1281, 8, 128), jnp.bfloat16)
+    compiled = jax.jit(decode_attention).lower(
+        _spec(one_chip, (8, 1, 32, 128), jnp.bfloat16), cache, cache,
+        _spec(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    for repeated in ("[8,1281,8,4,128]", "[8,1281,32,128]"):
+        assert repeated not in text
+    assert "multiply_reduce_fusion" not in text
