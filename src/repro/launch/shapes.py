@@ -43,6 +43,9 @@ MICROBATCH: Dict[str, int] = {
     "qwen3_4b": 2,
     "seamless_m4t_medium": 1,
     "xlstm_1_3b": 2,
+    # 27 latent-attention layers: per layer about 4/3 of the saved
+    # attention activations of the 48-layer multi-head stack it was sized
+    # for, in all about 3/4 of them (an estimate), so 2 is kept
     "moonshot_v1_16b_a3b": 2,
     "olmoe_1b_7b": 1,
     "pixtral_12b": 4,

@@ -52,14 +52,18 @@ def multi_head_attention(
     q_offset: int = 0,            # absolute position of q[0] (for decode)
     chunk_q: int = 1024,
     causal_slice: bool = False,   # §Perf: triangle slicing (unrolled path)
+    scale: Optional[float] = None,  # default head_dim ** -0.5
 ) -> jnp.ndarray:
-    """Chunked masked attention.  Handles GQA by repeating KV heads."""
+    """Chunked masked attention.  Handles GQA by repeating KV heads.  The
+    values may be narrower than the keys (latent attention's decompressed
+    heads); the output has the values' width."""
     b, sq, h, hd = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]
     q_per_kv = h // k.shape[2]
     k = _repeat_kv(k, q_per_kv)
     v = _repeat_kv(v, q_per_kv)
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
 
     kpos = jnp.arange(sk)
 
@@ -104,7 +108,7 @@ def multi_head_attention(
             return None, attend_chunk(qi, k, v, mask_for(qpos), scale)
 
         _, out = jax.lax.scan(body, None, (jnp.arange(n_chunks), qc))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, hd)
+    return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dv)
 
 
 def decode_attention_sp(q, k_cache, v_cache, cache_len) -> jnp.ndarray:
@@ -172,6 +176,7 @@ def decode_attention(
     k_cache: jnp.ndarray,         # (B, S, Hkv, hd)
     v_cache: jnp.ndarray,         # (B, S, Hkv, hd)
     cache_len: jnp.ndarray | int, # valid prefix length (scalar or (B,))
+    scale: Optional[float] = None,  # default head_dim ** -0.5
 ) -> jnp.ndarray:
     """Single-token attention against a (possibly padded) KV cache.
 
@@ -184,11 +189,15 @@ def decode_attention(
     products.  Same precision as the repeated form: operands in their
     own dtype, scores and softmax in float32, probabilities cast to the
     cache dtype for the product with V; for ``G = 1`` it is the same
-    contraction."""
+    contraction.  The values may be narrower than the keys: latent
+    attention's absorbed decode (one KV head of ``[c_kv ‖ k_pe]``, values
+    ``c_kv``, scale from the decompressed head width) is this contraction,
+    and the oracle of ``kernels/mla_decode.py``."""
     b, _, h, hd = q.shape
-    sk, hkv = k_cache.shape[1], k_cache.shape[2]
+    sk, hkv, dv = k_cache.shape[1], k_cache.shape[2], v_cache.shape[-1]
     qg = q.reshape(b, hkv, h // hkv, hd)
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache).astype(jnp.float32) * scale
     kpos = jnp.arange(sk)
     cache_len = jnp.asarray(cache_len)
@@ -201,4 +210,4 @@ def decode_attention(
     # the (g, k) output order lowers P.V as the repeated form lowered it,
     # so G = 1 gives the same bits; the TPU compiler folds the transpose
     o = jnp.einsum("bkgs,bskd->bgkd", p.astype(v_cache.dtype), v_cache)
-    return o.transpose(0, 2, 1, 3).reshape(b, 1, h, hd)
+    return o.transpose(0, 2, 1, 3).reshape(b, 1, h, dv)

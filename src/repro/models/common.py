@@ -49,11 +49,28 @@ class ModelConfig:
     qk_norm: bool = False
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    # --- latent attention (MLA, DeepSeek-V2/V3): on when kv_lora_rank > 0;
+    #     the cache holds one compressed KV row per position ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # the router's width
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # expert-parallel (mesh) path only
     router_aux_coef: float = 0.01
+    moe_d_ff: int = 0                # routed expert width (0: d_ff)
+    n_shared_experts: int = 0        # run on every token, as one SwiGLU
+    #                                  of width n_shared_experts * moe_d_ff
+    first_k_dense: int = 0           # leading layers with a dense SwiGLU
+    #                                  of width d_ff instead of experts
+    scoring_func: str = "softmax"    # "softmax" | "sigmoid" (experts
+    #                                  chosen by score plus a learned
+    #                                  correction bias, DeepSeek-V3)
+    routed_scaling: float = 1.0      # scale of the normalised weights
+    n_held_experts: int = 0          # experts held here (0: all); the
+    first_held_expert: int = 0       # router still routes over n_experts
     # --- recurrentgemma / hybrid ---
     window: int = 0                  # sliding local-attention window (0 = full)
     lru_width: int = 0
@@ -103,6 +120,14 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_held_experts or self.n_experts
 
     def n_params(self) -> int:
         """Parameter count, derived from the real param specs (no alloc)."""
@@ -207,6 +232,27 @@ def mlp_forward(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     u = jnp.dot(x, p["w_up"])
     h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
     return jnp.dot(h, p["w_down"])
+
+
+def map_token_chunks(fn: Callable, rows, chunk: int):
+    """``fn(rows)`` over row chunks of at most ``chunk`` rows.
+
+    ``rows`` is a pytree of arrays that share their leading (token) axis;
+    ``fn`` maps such a pytree to one array with that leading axis.  Above
+    ``chunk`` rows the tokens run through ``lax.map`` in equal chunks
+    (zero rows pad the last one and are dropped), so the intermediates of
+    a wide layer hold ``chunk`` rows at a time, not every token's."""
+    t = jax.tree.leaves(rows)[0].shape[0]
+    if t <= chunk:
+        return fn(rows)
+    n = -(-t // chunk)
+    c = -(-t // n)
+
+    def split(a):
+        a = jnp.pad(a, ((0, n * c - t),) + ((0, 0),) * (a.ndim - 1))
+        return a.reshape(n, c, *a.shape[1:])
+    out = jax.lax.map(fn, jax.tree.map(split, rows))
+    return out.reshape(n * c, *out.shape[2:])[:t]
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn
 from repro.models import common as cm
+from repro.models import mla
 from repro.models.common import ModelConfig
 
 # ---------------------------------------------------------------------------
@@ -23,21 +24,25 @@ from repro.models.common import ModelConfig
 # ---------------------------------------------------------------------------
 
 
-def _layer_init(cfg: ModelConfig):
+def _layer_init(cfg: ModelConfig, moe_ffn: bool):
     d, h, hkv, hd, ff, dt = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                              cfg.hd, cfg.d_ff, cfg.dtype)
 
     def init_one(key):
         ks = jax.random.split(key, 8)
-        p = {
-            "ln1": jnp.zeros((d,), dt),
-            "wq": cm.dense_init(ks[0], (d, h, hd), dt),
-            "wk": cm.dense_init(ks[1], (d, hkv, hd), dt),
-            "wv": cm.dense_init(ks[2], (d, hkv, hd), dt),
-            "wo": cm.dense_init(ks[3], (h, hd, d), dt, in_axis=(0, 1)),
-            "ln2": jnp.zeros((d,), dt),
-        }
-        if cfg.n_experts > 0:
+        if cfg.kv_lora_rank:
+            p = {"ln1": jnp.zeros((d,), dt), **mla.params(ks[0], cfg),
+                 "ln2": jnp.zeros((d,), dt)}
+        else:
+            p = {
+                "ln1": jnp.zeros((d,), dt),
+                "wq": cm.dense_init(ks[0], (d, h, hd), dt),
+                "wk": cm.dense_init(ks[1], (d, hkv, hd), dt),
+                "wv": cm.dense_init(ks[2], (d, hkv, hd), dt),
+                "wo": cm.dense_init(ks[3], (h, hd, d), dt, in_axis=(0, 1)),
+                "ln2": jnp.zeros((d,), dt),
+            }
+        if moe_ffn:
             from repro.models import moe
 
             p["moe"] = moe.moe_params(ks[4], cfg)
@@ -51,18 +56,22 @@ def _layer_init(cfg: ModelConfig):
     return init_one
 
 
-def _layer_specs(cfg: ModelConfig) -> dict:
+def _layer_specs(cfg: ModelConfig, moe_ffn: bool) -> dict:
     d, h, hkv, hd, ff, dt = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                              cfg.hd, cfg.d_ff, cfg.dtype)
-    p = {
-        "ln1": jax.ShapeDtypeStruct((d,), dt),
-        "wq": jax.ShapeDtypeStruct((d, h, hd), dt),
-        "wk": jax.ShapeDtypeStruct((d, hkv, hd), dt),
-        "wv": jax.ShapeDtypeStruct((d, hkv, hd), dt),
-        "wo": jax.ShapeDtypeStruct((h, hd, d), dt),
-        "ln2": jax.ShapeDtypeStruct((d,), dt),
-    }
-    if cfg.n_experts > 0:
+    if cfg.kv_lora_rank:
+        p = {"ln1": jax.ShapeDtypeStruct((d,), dt), **mla.specs(cfg),
+             "ln2": jax.ShapeDtypeStruct((d,), dt)}
+    else:
+        p = {
+            "ln1": jax.ShapeDtypeStruct((d,), dt),
+            "wq": jax.ShapeDtypeStruct((d, h, hd), dt),
+            "wk": jax.ShapeDtypeStruct((d, hkv, hd), dt),
+            "wv": jax.ShapeDtypeStruct((d, hkv, hd), dt),
+            "wo": jax.ShapeDtypeStruct((h, hd, d), dt),
+            "ln2": jax.ShapeDtypeStruct((d,), dt),
+        }
+    if moe_ffn:
         from repro.models import moe
 
         p["moe"] = moe.moe_specs(cfg)
@@ -74,25 +83,38 @@ def _layer_specs(cfg: ModelConfig) -> dict:
     return p
 
 
-def _layer_axes(cfg: ModelConfig) -> dict:
-    p = {
-        "ln1": (None,),
-        "wq": ("embed", "heads", None),
-        "wk": ("embed", "kv", None),
-        "wv": ("embed", "kv", None),
-        "wo": ("heads", None, "embed"),
-        "ln2": (None,),
-    }
-    if cfg.n_experts > 0:
+def _layer_axes(cfg: ModelConfig, moe_ffn: bool) -> dict:
+    if cfg.kv_lora_rank:
+        p = {"ln1": (None,), **mla.AXES, "ln2": (None,)}
+    else:
+        p = {
+            "ln1": (None,),
+            "wq": ("embed", "heads", None),
+            "wk": ("embed", "kv", None),
+            "wv": ("embed", "kv", None),
+            "wo": ("heads", None, "embed"),
+            "ln2": (None,),
+        }
+    if moe_ffn:
         from repro.models import moe
 
-        p["moe"] = dict(moe.MOE_AXES)
+        p["moe"] = moe.moe_axes(cfg)
     else:
         p["mlp"] = dict(cm.MLP_AXES)
     if cfg.qk_norm:
         p["q_norm"] = (None,)
         p["k_norm"] = (None,)
     return p
+
+
+def _groups(cfg: ModelConfig):
+    """(group, layers, expert FFN) of each stacked layer group the model
+    has, in the order they run: the leading dense layers
+    (``first_k_dense``, a SwiGLU of width ``d_ff``), then the rest
+    (experts when ``n_experts``)."""
+    k = cfg.first_k_dense
+    out = [("dense_layers", k, False)] if k else []
+    return out + [("layers", cfg.n_layers - k, cfg.n_experts > 0)]
 
 
 # ---------------------------------------------------------------------------
@@ -104,11 +126,15 @@ def init(cfg: ModelConfig, key) -> dict:
     k_emb, k_layers, k_head, k_fe = jax.random.split(key, 4)
     params = {
         "embed": cm.embed_init(k_emb, (cfg.vocab, cfg.d_model), cfg.dtype),
-        "layers": cm.stack_layer_params(_layer_init(cfg), k_layers,
-                                        cfg.n_layers),
         "final_norm": jnp.zeros((cfg.d_model,), cfg.dtype),
         "lm_head": cm.dense_init(k_head, (cfg.d_model, cfg.vocab), cfg.dtype),
     }
+    # the last group keeps the layers' key, so a one-group model draws
+    # the weights it drew before the dense-then-expert stack existed
+    for i, (name, n, moe_ffn) in enumerate(reversed(_groups(cfg))):
+        params[name] = cm.stack_layer_params(
+            _layer_init(cfg, moe_ffn),
+            jax.random.fold_in(k_layers, i) if i else k_layers, n)
     if cfg.frontend:
         params["frontend_proj"] = cm.dense_init(
             k_fe, (cfg.frontend_dim, cfg.d_model), cfg.dtype)
@@ -118,10 +144,11 @@ def init(cfg: ModelConfig, key) -> dict:
 def param_specs(cfg: ModelConfig) -> dict:
     p = {
         "embed": jax.ShapeDtypeStruct((cfg.vocab, cfg.d_model), cfg.dtype),
-        "layers": cm.stacked_specs(_layer_specs(cfg), cfg.n_layers),
         "final_norm": jax.ShapeDtypeStruct((cfg.d_model,), cfg.dtype),
         "lm_head": jax.ShapeDtypeStruct((cfg.d_model, cfg.vocab), cfg.dtype),
     }
+    for name, n, moe_ffn in _groups(cfg):
+        p[name] = cm.stacked_specs(_layer_specs(cfg, moe_ffn), n)
     if cfg.frontend:
         p["frontend_proj"] = jax.ShapeDtypeStruct(
             (cfg.frontend_dim, cfg.d_model), cfg.dtype)
@@ -131,10 +158,11 @@ def param_specs(cfg: ModelConfig) -> dict:
 def logical_axes(cfg: ModelConfig) -> dict:
     p = {
         "embed": ("vocab", "embed"),
-        "layers": cm.stacked_axes(_layer_axes(cfg)),
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
     }
+    for name, _, moe_ffn in _groups(cfg):
+        p[name] = cm.stacked_axes(_layer_axes(cfg, moe_ffn))
     if cfg.frontend:
         p["frontend_proj"] = (None, "embed")
     return p
@@ -185,6 +213,10 @@ def tp_attn_weights(cfg: ModelConfig, p: dict):
 
 def _attn_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                 positions: jnp.ndarray, *, window: int = 0) -> jnp.ndarray:
+    if cfg.kv_lora_rank:
+        o, _, _ = mla.attend(cfg, p, cm.rms_norm(x, p["ln1"], cfg.norm_eps),
+                             positions)
+        return x + o
     wq, wk, wv, wo, _ = tp_attn_weights(cfg, p)
     h = cm.rms_norm(x, p["ln1"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, wq)
@@ -203,7 +235,7 @@ def _attn_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
 def _ffn_block(cfg: ModelConfig, p: dict, x: jnp.ndarray):
     """Returns (x_out, aux_loss)."""
     h = cm.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.n_experts > 0:
+    if "moe" in p:
         from repro.models import moe
 
         y, aux = moe.moe_ffn(cfg, p["moe"], h)
@@ -235,8 +267,10 @@ def forward(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
         xc, a = _ffn_block(cfg, lp, xc)
         return xc, aux + a
 
-    (x, aux) = cm.scan_layers(body, (x, jnp.float32(0.0)),
-                              params["layers"], cfg)
+    carry = (x, jnp.float32(0.0))
+    for name, _, _ in _groups(cfg):
+        carry = cm.scan_layers(body, carry, params[name], cfg)
+    x, aux = carry
     x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.dot(x, params["lm_head"]).astype(jnp.float32)
     if return_aux:
@@ -255,8 +289,12 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
     """Returns (last-position logits (B,V), kv cache).
 
     cache = {"k": (L,B,max_len,Hkv,hd), "v": ..., "len": int32[]} —
-    ``max_len`` (default S + 64) reserves decode headroom.
+    ``max_len`` (default S + 64) reserves decode headroom.  A latent-
+    attention model caches its latent rows instead (``_latent_prefill``).
     """
+    if cfg.kv_lora_rank:
+        return _latent_prefill(cfg, params, tokens, frontend_embeds,
+                               max_len)
     x = embed_tokens(cfg, params, tokens, frontend_embeds)
     s = tokens.shape[1]
     positions = jnp.arange(s)
@@ -318,6 +356,8 @@ def decode_step(cfg: ModelConfig, params: dict, token: jnp.ndarray,
     """token (B,) int32; cache from ``prefill``.  One-token step.
 
     Returns (logits (B,V), updated cache)."""
+    if cfg.kv_lora_rank:
+        return _latent_decode_step(cfg, params, token, cache)
     x = jnp.take(params["embed"], token[:, None], axis=0)  # (B,1,D)
     positions = jnp.reshape(cache["len"], (1,))
 
@@ -351,7 +391,89 @@ def decode_step(cfg: ModelConfig, params: dict, token: jnp.ndarray,
     return logits, {"k": ks, "v": vs, "len": cache["len"] + 1}
 
 
+# ---------------------------------------------------------------------------
+# Serving a latent-attention model: the stacked latent cache is carried
+# through every group's layer scan, and each layer writes only its own
+# rows into it (no per-group slice or concatenation of the cache)
+# ---------------------------------------------------------------------------
+
+#: tokens per pass of a prefill's feed-forward blocks: bounds the dense
+#: layer's and the experts' intermediates (the sorted slots too) of a
+#: long prefill; each token's result is its own, so chunks change nothing
+PREFILL_CHUNK = 8192
+
+
+def _scan_groups(cfg: ModelConfig, params: dict, body, carry):
+    """``body(carry, (layer params, layer index)) -> (carry, None)`` over
+    every layer, group after group."""
+    first = 0
+    for name, n, _ in _groups(cfg):
+        carry, _ = cm.scan_or_unroll(
+            body, carry, (params[name], first + jnp.arange(n)),
+            cfg.scan_layers)
+        first += n
+    return carry
+
+
+def _latent_prefill(cfg: ModelConfig, params: dict, tokens, frontend_embeds,
+                    max_len):
+    x = embed_tokens(cfg, params, tokens, frontend_embeds)
+    b, s = tokens.shape
+    positions = jnp.arange(s)
+    spec = cache_specs(cfg, b, max(max_len if max_len is not None
+                                   else s + 64, s))
+    cache = (jnp.zeros(spec["ckv"].shape, cfg.dtype),
+             jnp.zeros(spec["kpe"].shape, cfg.dtype))
+
+    def body(carry, layer_in):
+        xc, (ckv, kpe) = carry
+        lp, i = layer_in
+        h = cm.rms_norm(xc, lp["ln1"], cfg.norm_eps)
+        o, c, k_pe = mla.attend(cfg, lp, h, positions)
+        ckv = jax.lax.dynamic_update_slice(ckv, c[None], (i, 0, 0, 0))
+        kpe = jax.lax.dynamic_update_slice(kpe, k_pe.swapaxes(1, 2)[None],
+                                           (i, 0, 0, 0))
+        xc = cm.map_token_chunks(
+            lambda t: _ffn_block(cfg, lp, t[None])[0][0],
+            (xc + o).reshape(b * s, -1), PREFILL_CHUNK)
+        return (xc.reshape(b, s, -1), (ckv, kpe)), None
+
+    x, (ckv, kpe) = _scan_groups(cfg, params, body, (x, cache))
+    x = cm.rms_norm(x[:, -1, :], params["final_norm"], cfg.norm_eps)
+    logits = jnp.dot(x, params["lm_head"]).astype(jnp.float32)
+    return logits, {"ckv": ckv, "kpe": kpe, "len": jnp.int32(s)}
+
+
+def _latent_decode_step(cfg: ModelConfig, params: dict, token, cache):
+    x = jnp.take(params["embed"], token[:, None], axis=0)  # (B,1,D)
+    pos = cache["len"]
+
+    def body(carry, layer_in):
+        xc, (ckv, kpe) = carry
+        lp, i = layer_in
+        h = cm.rms_norm(xc, lp["ln1"], cfg.norm_eps)
+        o, ckv, kpe = mla.decode(cfg, lp, h, ckv, kpe, i, pos)
+        xc, _ = _ffn_block(cfg, lp, xc + o)
+        return (xc, (ckv, kpe)), None
+
+    x, (ckv, kpe) = _scan_groups(cfg, params, body,
+                                 (x, (cache["ckv"], cache["kpe"])))
+    x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = jnp.dot(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+    return logits, {"ckv": ckv, "kpe": kpe, "len": pos + 1}
+
+
 def cache_specs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    if cfg.kv_lora_rank:
+        t = mla.cache_len(max_len)
+        lb = (cfg.n_layers, batch)
+        return {
+            "ckv": jax.ShapeDtypeStruct(lb + (t, cfg.kv_lora_rank),
+                                        cfg.dtype),
+            "kpe": jax.ShapeDtypeStruct(lb + (cfg.qk_rope_head_dim, t),
+                                        cfg.dtype),
+            "len": jax.ShapeDtypeStruct((), jnp.int32),
+        }
     shp = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.hd)
     return {
         "k": jax.ShapeDtypeStruct(shp, cfg.dtype),
@@ -361,5 +483,8 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
 
 
 def cache_axes(cfg: ModelConfig) -> dict:
+    if cfg.kv_lora_rank:
+        return {"ckv": ("layer", "batch", "kv_seq", None),
+                "kpe": ("layer", "batch", None, "kv_seq"), "len": ()}
     ax = ("layer", "batch", "kv_seq", "kv", None)
     return {"k": ax, "v": ax, "len": ()}

@@ -123,3 +123,50 @@ def test_decode_attention_reads_the_cache_in_place(one_chip):
     for repeated in ("[8,1281,8,4,128]", "[8,1281,32,128]"):
         assert repeated not in text
     assert "multiply_reduce_fusion" not in text
+
+
+def test_latent_decode_step_compiles_with_named_kernels(one_chip,
+                                                        monkeypatch):
+    """Moonlight-16B-A3B's decode step with one EP8 chip's 8 of 64
+    experts, at the ``moonlight_16b_a3b.decode64`` cell's shapes (batch
+    64, cache 1024 + 257 rounded up to 1408): the latent attention and
+    the shared experts are the named kernels, the held experts the
+    compiler's ``ragged-dot``.  The caches are read in place:
+    their only copies are of the step's undonated input, one each, and
+    the step needs no other device memory."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import registry
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(configs.get("moonshot_v1_16b_a3b"),
+                              n_held_experts=8, remat=False)
+
+    def spec(s):
+        return _spec(one_chip, s.shape, s.dtype)
+    params = jax.tree.map(spec, registry.param_specs(cfg))
+    cache = jax.tree.map(spec, registry.cache_specs(cfg, 64, 1281))
+    assert cache["ckv"].shape == (27, 64, 1408, 512)
+    compiled = jax.jit(lambda p, t, c: registry.decode_step(cfg, p, t, c)
+                       ).lower(params, _spec(one_chip, (64,), jnp.int32),
+                               cache).compile()
+    text = compiled.as_text()
+    for name in ("%mla_decode_attention", "%moe_shared", "%ragged-dot"):
+        assert name in text, name
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and ("[27,64,1408,512]" in ln
+                                     or "[27,64,64,1408]" in ln)]
+    assert len(copies) == 2, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**28
+
+
+@pytest.mark.parametrize("t", [64, 8192])
+def test_shared_expert_kernel_compiles(one_chip, t):
+    """The shared experts' kernel at a decode step's 64 tokens and a
+    prefill chunk's 8192, Moonlight's widths."""
+    from repro.kernels.moe import moe_shared
+    bf = jnp.bfloat16
+    assert _kernels_in(jax.jit(moe_shared).lower(
+        _spec(one_chip, (t, 2048), bf), _spec(one_chip, (2048, 2816), bf),
+        _spec(one_chip, (2048, 2816), bf),
+        _spec(one_chip, (2816, 2048), bf)).compile()) == 1
