@@ -288,9 +288,12 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
             max_len: Optional[int] = None) -> Tuple[jnp.ndarray, dict]:
     """Returns (last-position logits (B,V), kv cache).
 
-    cache = {"k": (L,B,max_len,Hkv,hd), "v": ..., "len": int32[]} —
-    ``max_len`` (default S + 64) reserves decode headroom.  A latent-
-    attention model caches its latent rows instead (``_latent_prefill``).
+    cache = {"k": (L,B,Hkv,T,hd), "v": ..., "len": int32[]}: each
+    (sequence, KV head)'s positions are one contiguous (T, hd) matrix,
+    ``T`` = ``cache_rows(max_len)``, as the decode attention reads them
+    (``decode_step``).  ``max_len`` (default S + 64) reserves decode
+    headroom.  A latent-attention model caches its latent rows instead
+    (``_latent_prefill``).
     """
     if cfg.kv_lora_rank:
         return _latent_prefill(cfg, params, tokens, frontend_embeds,
@@ -312,19 +315,41 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
         o = attn.multi_head_attention(q, k, v, causal=True, window=cfg.window)
         xc = xc + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
         xc, _ = _ffn_block(cfg, lp, xc)
-        return xc, (k, v)
+        return xc, (k.swapaxes(1, 2), v.swapaxes(1, 2))
 
     fn = cm.maybe_remat(body, cfg)
     x, (ks, vs) = cm.scan_or_unroll(fn, x, params["layers"],
                                     cfg.scan_layers)
     x = cm.rms_norm(x[:, -1:, :], params["final_norm"], cfg.norm_eps)
     logits = jnp.dot(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
-    cap = max_len if max_len is not None else s + 64
+    cap = cache_rows(max(max_len if max_len is not None else s + 64, s))
     if cap > s:
-        pad = ((0, 0), (0, 0), (0, cap - s), (0, 0), (0, 0))
+        pad = ((0, 0), (0, 0), (0, 0), (0, cap - s), (0, 0))
         ks, vs = jnp.pad(ks, pad), jnp.pad(vs, pad)
     cache = {"k": ks, "v": vs, "len": jnp.int32(s)}
     return logits, cache
+
+
+def cache_rows(max_len: int) -> int:
+    """Positions a dense K/V cache of ``max_len`` holds: rounded up to
+    the 16 rows of a bfloat16 (16, 128) tile, so that the TPU's own
+    layout of the cache is row-major, the layout ``decode_step`` keeps it
+    in (at other lengths the TPU lays it out otherwise, and the step
+    copies it whole)."""
+    return -(-max_len // 16) * 16
+
+
+def _row_major(cache: jnp.ndarray) -> jnp.ndarray:
+    """Keep the carried (L, B, Hkv, T, hd) stack row-major.  Left to
+    itself the TPU compiler lays the loop's cache out position-major for
+    the row write, and then either copies the whole cache in and out of
+    the step or relays out each layer for the attention's products; in
+    row-major the row is written in place and the products read their
+    layer straight out of the stack."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        cache, Layout(major_to_minor=tuple(range(cache.ndim))))
 
 
 def _pin_seq_sharding(kc: jnp.ndarray, vc: jnp.ndarray):
@@ -351,58 +376,6 @@ def _pin_seq_sharding(kc: jnp.ndarray, vc: jnp.ndarray):
     return cst(kc), cst(vc)
 
 
-def decode_step(cfg: ModelConfig, params: dict, token: jnp.ndarray,
-                cache: dict) -> Tuple[jnp.ndarray, dict]:
-    """token (B,) int32; cache from ``prefill``.  One-token step.
-
-    Returns (logits (B,V), updated cache)."""
-    if cfg.kv_lora_rank:
-        return _latent_decode_step(cfg, params, token, cache)
-    x = jnp.take(params["embed"], token[:, None], axis=0)  # (B,1,D)
-    positions = jnp.reshape(cache["len"], (1,))
-
-    def body(xc, layer_in):
-        lp, kc, vc = layer_in
-        h = cm.rms_norm(xc, lp["ln1"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        if cfg.qk_norm:
-            q = cm.head_rms_norm(q, lp["q_norm"], cfg.norm_eps)
-            k = cm.head_rms_norm(k, lp["k_norm"], cfg.norm_eps)
-        q = cm.apply_rope(q, positions, cfg.rope_theta)
-        k = cm.apply_rope(k, positions, cfg.rope_theta)
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, k, cache["len"], axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, v, cache["len"], axis=1)
-        if cfg.sp_decode:
-            kc, vc = _pin_seq_sharding(kc, vc)
-            o = attn.decode_attention_sp(q, kc, vc, cache["len"] + 1)
-        else:
-            o = attn.decode_attention(q, kc, vc, cache["len"] + 1)
-        xc = xc + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
-        xc, _ = _ffn_block(cfg, lp, xc)
-        return xc, (kc, vc)
-
-    x, (ks, vs) = cm.scan_or_unroll(
-        body, x, (params["layers"], cache["k"], cache["v"]),
-        cfg.scan_layers)
-    x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.dot(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": ks, "v": vs, "len": cache["len"] + 1}
-
-
-# ---------------------------------------------------------------------------
-# Serving a latent-attention model: the stacked latent cache is carried
-# through every group's layer scan, and each layer writes only its own
-# rows into it (no per-group slice or concatenation of the cache)
-# ---------------------------------------------------------------------------
-
-#: tokens per pass of a prefill's feed-forward blocks: bounds the dense
-#: layer's and the experts' intermediates (the sorted slots too) of a
-#: long prefill; each token's result is its own, so chunks change nothing
-PREFILL_CHUNK = 8192
-
-
 def _scan_groups(cfg: ModelConfig, params: dict, body, carry):
     """``body(carry, (layer params, layer index)) -> (carry, None)`` over
     every layer, group after group."""
@@ -413,6 +386,69 @@ def _scan_groups(cfg: ModelConfig, params: dict, body, carry):
             cfg.scan_layers)
         first += n
     return carry
+
+
+def decode_step(cfg: ModelConfig, params: dict, token: jnp.ndarray,
+                cache: dict) -> Tuple[jnp.ndarray, dict]:
+    """token (B,) int32; cache from ``prefill``.  One-token step.
+
+    The stacked K/V cache is carried through the layer scan: each layer
+    writes only its new row, at ``(layer, len)``, and attends to its own
+    layer of the carry, so a caller that donates the cache has it
+    updated in place.  Returns (logits (B,V), updated cache)."""
+    if cfg.kv_lora_rank:
+        return _latent_decode_step(cfg, params, token, cache)
+    x = jnp.take(params["embed"], token[:, None], axis=0)  # (B,1,D)
+    pos = cache["len"]
+    positions = jnp.reshape(pos, (1,))
+
+    def body(carry, layer_in):
+        xc, (kc, vc) = carry
+        lp, i = layer_in
+        h = cm.rms_norm(xc, lp["ln1"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        if cfg.qk_norm:
+            q = cm.head_rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = cm.head_rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        q = cm.apply_rope(q, positions, cfg.rope_theta)
+        k = cm.apply_rope(k, positions, cfg.rope_theta)
+        at = (i, 0, 0, pos, 0)
+        kc = _row_major(jax.lax.dynamic_update_slice(
+            kc, k.swapaxes(1, 2)[None], at))
+        vc = _row_major(jax.lax.dynamic_update_slice(
+            vc, v.swapaxes(1, 2)[None], at))
+        # this layer as (B, T, Hkv, hd); the products read it in place
+        kl = jax.lax.dynamic_index_in_dim(kc, i, keepdims=False).swapaxes(1, 2)
+        vl = jax.lax.dynamic_index_in_dim(vc, i, keepdims=False).swapaxes(1, 2)
+        if cfg.sp_decode:
+            kl, vl = _pin_seq_sharding(kl, vl)
+            o = attn.decode_attention_sp(q, kl, vl, pos + 1)
+        else:
+            o = attn.decode_attention(q, kl, vl, pos + 1)
+        xc = xc + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+        xc, _ = _ffn_block(cfg, lp, xc)
+        return (xc, (kc, vc)), None
+
+    x, (ks, vs) = _scan_groups(cfg, params, body,
+                               (x, (cache["k"], cache["v"])))
+    x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = jnp.dot(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+    return logits, {"k": ks, "v": vs, "len": pos + 1}
+
+
+# ---------------------------------------------------------------------------
+# Serving a latent-attention model: as in ``decode_step``, the stacked
+# latent cache is carried through every group's layer scan, and each layer
+# writes only its own rows into it (no per-group slice or concatenation of
+# the cache)
+# ---------------------------------------------------------------------------
+
+#: tokens per pass of a prefill's feed-forward blocks: bounds the dense
+#: layer's and the experts' intermediates (the sorted slots too) of a
+#: long prefill; each token's result is its own, so chunks change nothing
+PREFILL_CHUNK = 8192
 
 
 def _latent_prefill(cfg: ModelConfig, params: dict, tokens, frontend_embeds,
@@ -464,6 +500,10 @@ def _latent_decode_step(cfg: ModelConfig, params: dict, token, cache):
 
 
 def cache_specs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    """Shapes of ``prefill``'s cache.  Dense K/V: ``(L, B, Hkv, T, hd)``,
+    ``T`` = ``cache_rows(max_len)``; latent: ``(L, B, T, rank)`` rows and
+    ``(L, B, rope, T)`` rope keys, ``T`` the kernel's block multiple
+    (``mla.cache_len``)."""
     if cfg.kv_lora_rank:
         t = mla.cache_len(max_len)
         lb = (cfg.n_layers, batch)
@@ -474,7 +514,7 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
                                         cfg.dtype),
             "len": jax.ShapeDtypeStruct((), jnp.int32),
         }
-    shp = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.hd)
+    shp = (cfg.n_layers, batch, cfg.n_kv_heads, cache_rows(max_len), cfg.hd)
     return {
         "k": jax.ShapeDtypeStruct(shp, cfg.dtype),
         "v": jax.ShapeDtypeStruct(shp, cfg.dtype),
@@ -486,5 +526,7 @@ def cache_axes(cfg: ModelConfig) -> dict:
     if cfg.kv_lora_rank:
         return {"ckv": ("layer", "batch", "kv_seq", None),
                 "kpe": ("layer", "batch", None, "kv_seq"), "len": ()}
-    ax = ("layer", "batch", "kv_seq", "kv", None)
+    # the sequence takes the model axis (sequence-parallel decode); a
+    # "kv" name on the heads, which come first, would take it instead
+    ax = ("layer", "batch", None, "kv_seq", None)
     return {"k": ax, "v": ax, "len": ()}

@@ -30,6 +30,13 @@ class ServeStats:
 
 
 class BatchServer:
+    """Prefill and greedy decode of one static batch.
+
+    ``_decode(params, token, cache)`` consumes its cache: the argument is
+    donated, so the step updates the cache in place (dense K/V stored
+    ``(L, B, Hkv, T, hd)``, latent rows as ``prefill`` lays them out) and
+    the cache passed in is deleted.  Rebind it to the step's output."""
+
     def __init__(self, cfg: ModelConfig, params, max_new_tokens: int = 32,
                  eos_id: Optional[int] = None, pad_id: int = 0):
         self.cfg = cfg
@@ -42,7 +49,8 @@ class BatchServer:
                 cfg, p, t, frontend_embeds=fe,
                 max_len=t.shape[1] + max_new_tokens))
         self._decode = jax.jit(
-            lambda p, tok, cache: registry.decode_step(cfg, p, tok, cache))
+            lambda p, tok, cache: registry.decode_step(cfg, p, tok, cache),
+            donate_argnums=(2,))
 
     def generate(self, prompts: jnp.ndarray,
                  frontend_embeds=None) -> Dict:

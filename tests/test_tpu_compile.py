@@ -14,7 +14,9 @@ imported: only one process may load the TPU library, and every pytest
 worker imports every test file.  Keep every such compile in this one
 file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -158,6 +160,73 @@ def test_latent_decode_step_compiles_with_named_kernels(one_chip,
                                      or "[27,64,64,1408]" in ln)]
     assert len(copies) == 2, copies
     assert compiled.memory_analysis().temp_size_in_bytes < 2**28
+
+
+def _shapes(text: str):
+    """Instruction name -> dimensions, over every computation of the
+    module, fused ones included (names are unique across the module)."""
+    return {m.group(1): tuple(int(d) for d in m.group(2).split(",") if d)
+            for m in re.finditer(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text)}
+
+
+def _updates(text: str):
+    """The shapes of the updates of every ``dynamic-update-slice``."""
+    shapes = _shapes(text)
+    return [shapes[m.group(1)] for m in re.finditer(
+        r"dynamic-update-slice\(%[\w.\-]+, (%[\w.\-]+)", text)]
+
+
+@pytest.mark.parametrize("arch,batch", [("qwen3_4b", 8),
+                                        ("moonshot_v1_16b_a3b", 64)])
+def test_served_decode_step_updates_the_cache_in_place(one_chip,
+                                                       monkeypatch, arch,
+                                                       batch):
+    """``BatchServer._decode`` itself at the decode cells' shapes
+    (``qwen3_4b.decode``: batch 8; ``moonlight_16b_a3b.decode64``: batch
+    64, one EP8 chip's 8 experts; cache 1024 + 257 positions): the
+    donated cache is aliased to the step's output whole, no whole cache
+    is copied, the step needs under 256 MiB besides, and each dense
+    layer writes only its new row, never a whole layer's slice, and is
+    read in place: no copy of a layer's K or V anywhere in the program,
+    inside a fusion or not (a relayout of each layer for the attention's
+    products costs more than the whole cache's copy it replaces)."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import registry
+    from repro.serve.loop import BatchServer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(configs.get(arch), remat=False)
+    if cfg.n_experts:
+        cfg = dataclasses.replace(cfg, n_held_experts=8)
+
+    def spec(s):
+        return _spec(one_chip, s.shape, s.dtype)
+    params = jax.tree.map(spec, registry.param_specs(cfg))
+    cache = jax.tree.map(spec, registry.cache_specs(cfg, batch, 1281))
+    compiled = BatchServer(cfg, None)._decode.lower(
+        params, _spec(one_chip, (batch,), jnp.int32), cache).compile()
+    text = compiled.as_text()
+    leaves = jax.tree.leaves(cache)
+    whole = {"[" + ",".join(map(str, a.shape)) + "]" for a in leaves
+             if a.ndim}
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and any(w in ln for w in whole)]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in leaves)
+    assert mem.temp_size_in_bytes < 2**28
+    if not cfg.kv_lora_rank:
+        assert cache["k"].shape == (36, 8, 8, 1296, 128)
+        layer = math.prod(cache["k"].shape[1:])
+        updates = _updates(text)
+        assert updates
+        assert max(map(math.prod, updates)) < layer
+        shapes = _shapes(text)
+        big = [name for name in re.findall(r"(%[\w.\-]+) = \S+ copy\(", text)
+               if math.prod(shapes[name]) >= layer]
+        assert not big, big
 
 
 @pytest.mark.parametrize("t", [64, 8192])
