@@ -71,11 +71,10 @@ def model_flops_per_chip(rec: Dict) -> float:
     return 2.0 * n_act * shape.batch / chips
 
 
-def corrected_for(rec: Dict, variant: str = "") -> Optional[Dict]:
+def corrected_for(rec: Dict) -> Optional[Dict]:
     """Trip-count-corrected costs from launch/costcount.py, if present."""
-    suffix = f"__{variant}" if variant else ""
     f = (DRYRUN.parent / "costs"
-         / f"{rec['arch']}__{rec['shape']}__{rec['mesh']}{suffix}.json")
+         / f"{rec['arch']}__{rec['shape']}__{rec['mesh']}.json")
     if f.exists():
         c = json.loads(f.read_text())
         if c.get("status") == "ok":
@@ -83,16 +82,16 @@ def corrected_for(rec: Dict, variant: str = "") -> Optional[Dict]:
     return None
 
 
-def analyze(rec: Dict, variant: str = "") -> Optional[Dict]:
+def analyze(rec: Dict) -> Optional[Dict]:
     if rec["status"] != "ok":
         return None
-    corr = corrected_for(rec, variant)
+    corr = corrected_for(rec)
     if corr is not None:
         flops = corr["flops"]
         bts = corr["bytes"]
         coll_bytes = corr["coll_bytes"]
         coll = {"count": corr["coll_count"]}
-        source = f"corrected{'+' + variant if variant else ''}"
+        source = "corrected"
     else:
         flops = rec["flops_per_chip"]
         bts = rec["bytes_per_chip"]
@@ -124,11 +123,11 @@ def analyze(rec: Dict, variant: str = "") -> Optional[Dict]:
     }
 
 
-def load_all(mesh: str = "16x16", variant: str = "") -> List[Dict]:
+def load_all(mesh: str = "16x16") -> List[Dict]:
     rows = []
     for f in sorted(DRYRUN.glob(f"*__{mesh}.json")):
         rec = json.loads(f.read_text())
-        row = analyze(rec, variant)
+        row = analyze(rec)
         if row:
             rows.append(row)
     return rows

@@ -21,15 +21,13 @@ from repro.sim import (ChipRingTraining, DegradeLink, FailHost, FailTask,
                        Straggler, Topology)
 
 
-def resolve_cost(arch: str, variant: str = "") -> StepCost:
+def resolve_cost(arch: str) -> StepCost:
     try:
-        cost = StepCost.from_dryrun(arch, "train_4k", "2x16x16",
-                                    variant=variant)
-        src = f"dry-run artifact{' (' + variant + ')' if variant else ''}"
+        cost = StepCost.from_dryrun(arch, "train_4k", "2x16x16")
+        src = "dry-run artifact"
     except Exception:
         try:
-            cost = StepCost.from_dryrun(arch, "train_4k", "16x16",
-                                        variant=variant)
+            cost = StepCost.from_dryrun(arch, "train_4k", "16x16")
             src = "single-pod dry-run artifact"
         except Exception:
             cost = StepCost(compute_ns=5_000_000, ici_bytes=50_000_000)
@@ -41,10 +39,9 @@ def resolve_cost(arch: str, variant: str = "") -> StepCost:
     return cost
 
 
-def run(arch: str, n_steps: int = 4, variant: str = "",
-        chips_per_pod: int = 256):
+def run(arch: str, n_steps: int = 4, chips_per_pod: int = 256):
     spec = ClusterSpec(n_pods=2, chips_per_pod=chips_per_pod)
-    cost = resolve_cost(arch, variant)
+    cost = resolve_cost(arch)
     analytic = analytic_step_ns(spec, cost)
     print(f"  closed-form step time: {analytic/1e6:.2f} ms")
 
@@ -398,14 +395,12 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_4b")
     ap.add_argument("--steps", type=int, default=4)
-    ap.add_argument("--variant", default="",
-                    help="optimized cost variant, e.g. gather_causal")
     ap.add_argument("--skip-multihost", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes for CI")
     args = ap.parse_args()
     if args.smoke:
-        run(args.arch, n_steps=2, variant=args.variant, chips_per_pod=16)
+        run(args.arch, n_steps=2, chips_per_pod=16)
         if not args.skip_multihost:
             run_multihost(n_iters=60)
         run_scenarios(n_iters=40, n_steps=8,
@@ -415,7 +410,7 @@ if __name__ == "__main__":
             run_live_serve()
             run_autoscale(smoke=True)
     else:
-        run(args.arch, args.steps, args.variant)
+        run(args.arch, args.steps)
         if not args.skip_multihost:
             run_multihost()
         run_scenarios(multihost=not args.skip_multihost)

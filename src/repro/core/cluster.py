@@ -55,14 +55,10 @@ class StepCost:
 
     @staticmethod
     def from_dryrun(arch: str, shape: str, mesh: str = "16x16",
-                    cost: CostModel = CostModel(),
-                    variant: str = "") -> "StepCost":
+                    cost: CostModel = CostModel()) -> "StepCost":
         """Prefer the trip-count-corrected costs (results/costs, see
-        launch/costcount.py); fall back to the raw dry-run record.
-        ``variant`` selects an optimized §Perf configuration."""
-        suffix = f"__{variant}" if variant else ""
-        corrected = (RESULTS.parent / "costs"
-                     / f"{arch}__{shape}__{mesh}{suffix}.json")
+        launch/costcount.py); fall back to the raw dry-run record."""
+        corrected = RESULTS.parent / "costs" / f"{arch}__{shape}__{mesh}.json"
         if corrected.exists():
             rec = json.loads(corrected.read_text())
             if rec.get("status") == "ok":

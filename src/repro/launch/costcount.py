@@ -199,15 +199,12 @@ def _slstm_analytic(cfg, shape, mesh):
             "coll_bytes": 0.0, "coll_count": 0.0}
 
 
-def corrected_costs(arch: str, shape_name: str, multi_pod: bool,
-                    overrides: dict | None = None) -> dict:
+def corrected_costs(arch: str, shape_name: str, multi_pod: bool) -> dict:
     from repro import configs
     from repro.launch import shapes as shp
     from repro.launch.mesh import make_production_mesh
 
     cfg = configs.get(arch)
-    if overrides:
-        cfg = dataclasses.replace(cfg, **overrides)
     shape = shp.SHAPES[shape_name]
     mesh_tag = "2x16x16" if multi_pod else "16x16"
     skip = shp.applicable(cfg, shape)
@@ -248,24 +245,21 @@ def corrected_costs(arch: str, shape_name: str, multi_pod: bool,
 
     out = {"arch": arch, "shape": shape_name, "mesh": mesh_tag,
            "status": "ok", "n_chips": int(mesh.devices.size),
-           "overrides": overrides or {},
            "corrected": result,
            "design_points": [dict(zip(metrics, r)) for r in rows]}
     return out
 
 
-def run_cell(arch, shape_name, multi_pod, verbose=True, overrides=None,
-             variant=""):
+def run_cell(arch, shape_name, multi_pod, verbose=True):
     mesh_tag = "2x16x16" if multi_pod else "16x16"
     try:
-        res = corrected_costs(arch, shape_name, multi_pod, overrides)
+        res = corrected_costs(arch, shape_name, multi_pod)
     except Exception as e:  # noqa: BLE001
         res = {"arch": arch, "shape": shape_name, "mesh": mesh_tag,
                "status": "error", "error": f"{type(e).__name__}: {e}",
                "traceback": traceback.format_exc()[-4000:]}
     RESULTS.mkdir(parents=True, exist_ok=True)
-    suffix = f"__{variant}" if variant else ""
-    (RESULTS / f"{arch}__{shape_name}__{mesh_tag}{suffix}.json").write_text(
+    (RESULTS / f"{arch}__{shape_name}__{mesh_tag}.json").write_text(
         json.dumps(res, indent=2))
     if verbose:
         if res["status"] == "ok":
@@ -288,38 +282,19 @@ def main(argv=None):
     ap.add_argument("--shape", default=None)
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--skip-existing", action="store_true")
-    ap.add_argument("--variant", default="",
-                    help="named optimization variant, e.g. tp_attention")
     args = ap.parse_args(argv)
-    overrides = {"tp_attention": {"tp_attention": True},
-                 "sp_decode": {"sp_decode": True},
-                 "gather_once": {"gather_weights_once": True},
-                 "dots": {"remat_policy": "dots"},
-                 "causal_slice": {"causal_slice": True},
-                 "tp_causal": {"tp_attention": True, "causal_slice": True},
-                 "tp_causal_dots": {"tp_attention": True,
-                                    "causal_slice": True,
-                                    "remat_policy": "dots"},
-                 "gather_causal": {"gather_weights_once": True,
-                                   "causal_slice": True},
-                 "tp_causal_gather": {"tp_attention": True,
-                                      "causal_slice": True,
-                                      "gather_weights_once": True},
-                 "": None}[args.variant]
     archs = [args.arch] if args.arch else configs.ARCHS
     shapes = [args.shape] if args.shape else list(shp.SHAPES)
     fails = 0
     for a in archs:
         for s in shapes:
             tag = "2x16x16" if args.multi_pod else "16x16"
-            suffix = f"__{args.variant}" if args.variant else ""
-            f = RESULTS / f"{a}__{s}__{tag}{suffix}.json"
+            f = RESULTS / f"{a}__{s}__{tag}.json"
             if args.skip_existing and f.exists():
                 prev = json.loads(f.read_text())
                 if prev.get("status") in ("ok", "n/a"):
                     continue
-            r = run_cell(a, s, args.multi_pod, overrides=overrides,
-                         variant=args.variant)
+            r = run_cell(a, s, args.multi_pod)
             fails += r["status"] == "error"
     sys.exit(1 if fails else 0)
 

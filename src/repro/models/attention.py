@@ -51,7 +51,6 @@ def multi_head_attention(
     window: int = 0,              # 0 = full; >0 = sliding local window
     q_offset: int = 0,            # absolute position of q[0] (for decode)
     chunk_q: int = 1024,
-    causal_slice: bool = False,   # §Perf: triangle slicing (unrolled path)
     scale: Optional[float] = None,  # default head_dim ** -0.5
 ) -> jnp.ndarray:
     """Chunked masked attention.  Handles GQA by repeating KV heads.  The
@@ -85,90 +84,16 @@ def multi_head_attention(
 
     from repro.parallel import ctx as pctx
 
+    def chunk(i, qi):
+        qpos = q_offset + i * chunk_q + jnp.arange(chunk_q)
+        return attend_chunk(qi, k, v, mask_for(qpos), scale)
+
     if pctx.get_unroll():
-        outs = []
-        for i in range(n_chunks):
-            qpos = q_offset + i * chunk_q + jnp.arange(chunk_q)
-            if causal_slice and causal and window == 0:
-                # causal triangle: chunk i only attends keys < chunk end
-                # (the jnp analogue of the flash kernel's block skipping;
-                # saves ~half the attention flops + masked-softmax work)
-                hi = min(q_offset + (i + 1) * chunk_q, sk)
-                ki, vi = k[:, :hi], v[:, :hi]
-                m = mask_for(qpos)[:, :hi]
-                outs.append(attend_chunk(qc[i], ki, vi, m, scale))
-            else:
-                outs.append(attend_chunk(qc[i], k, v, mask_for(qpos),
-                                         scale))
-        out = jnp.stack(outs)
+        out = jnp.stack([chunk(i, qc[i]) for i in range(n_chunks)])
     else:
-        def body(_, args):
-            i, qi = args
-            qpos = q_offset + i * chunk_q + jnp.arange(chunk_q)
-            return None, attend_chunk(qi, k, v, mask_for(qpos), scale)
-
-        _, out = jax.lax.scan(body, None, (jnp.arange(n_chunks), qc))
+        _, out = jax.lax.scan(lambda _, a: (None, chunk(*a)), None,
+                              (jnp.arange(n_chunks), qc))
     return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dv)
-
-
-def decode_attention_sp(q, k_cache, v_cache, cache_len) -> jnp.ndarray:
-    """Flash-decoding over the sequence-sharded KV cache (§Perf
-    sp_decode): an explicit shard_map keeps each chip's cache shard in
-    place — local partial softmax (max-trick) + tiny psum of (m, l, o)
-    over the ``model`` axis — instead of GSPMD's whole-cache re-gather
-    to kv-head sharding each layer.
-
-    q (B,1,H,hd); caches (B,S,Hkv,hd) with S sharded over 'model' and B
-    over DP axes; cache_len scalar."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.parallel import ctx as pctx
-
-    mesh = pctx.get_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
-        return decode_attention(q, k_cache, v_cache, cache_len)
-    m = mesh.shape["model"]
-    b, s = q.shape[0], k_cache.shape[1]
-    ba = pctx.batch_axes(mesh)
-    dp = pctx.dp_size(mesh)
-    bspec = ((ba if len(ba) > 1 else ba[0])
-             if (dp > 1 and b % dp == 0) else None)
-    s_loc = s // m
-
-    def local_fn(ql, kl, vl, ln):
-        # shard offset along the sequence axis
-        rank = jax.lax.axis_index("model")
-        base = rank * s_loc
-        hkv = kl.shape[2]
-        h = ql.shape[2]
-        kl = _repeat_kv(kl, h // hkv)
-        vl = _repeat_kv(vl, h // hkv)
-        scale = 1.0 / jnp.sqrt(jnp.float32(ql.shape[-1]))
-        sc = jnp.einsum("bqhd,bkhd->bhk", ql.astype(jnp.float32) * scale,
-                        kl.astype(jnp.float32))          # (B,H,s_loc)
-        valid = (base + jnp.arange(s_loc))[None, None, :] < ln
-        sc = jnp.where(valid, sc, NEG_INF)
-        m_loc = jnp.max(sc, axis=-1)                      # (B,H)
-        m_g = jax.lax.pmax(m_loc, "model")
-        p = jnp.exp(sc - m_g[..., None])
-        p = jnp.where(valid, p, 0.0)
-        l_loc = jnp.sum(p, axis=-1)                       # (B,H)
-        o_loc = jnp.einsum("bhk,bkhd->bhd", p,
-                           vl.astype(jnp.float32))        # (B,H,hd)
-        l_g = jax.lax.psum(l_loc, "model")
-        o_g = jax.lax.psum(o_loc, "model")
-        o = o_g / jnp.maximum(l_g, 1e-30)[..., None]
-        return o[:, None].astype(ql.dtype)                # (B,1,H,hd)
-
-    ln = jnp.asarray(cache_len).reshape(())
-    return jax.shard_map(
-        local_fn, mesh=mesh,
-        in_specs=(P(bspec, None, None, None),
-                  P(bspec, "model", None, None),
-                  P(bspec, "model", None, None), P()),
-        out_specs=P(bspec, None, None, None),
-        check_vma=False,
-    )(q, k_cache, v_cache, ln)
 
 
 def decode_attention(

@@ -90,28 +90,6 @@ class ModelConfig:
     # --- training-time knobs (overridable per shape) ---
     remat: bool = True
     scan_layers: bool = True
-    # --- beyond-paper optimization knobs (§Perf; default = baseline) ---
-    tp_attention: bool = False   # TP-aligned GQA: repeat KV weights to one
-    #                              kv head per q head + zero-pad heads to
-    #                              the model-axis width, so the attention
-    #                              einsums shard instead of replicating
-    #                              (numerically identical; see EXPERIMENTS)
-    sp_decode: bool = False      # pin decode attention to the sequence-
-    #                              sharded KV layout (flash-decoding style)
-    #                              instead of letting GSPMD reshard the
-    #                              cache to kv-head sharding per layer
-    #                              ("involuntary full rematerialization")
-    gather_weights_once: bool = False  # hoist the FSDP all-gather out of
-    #                              the microbatch/remat passes: gather bf16
-    #                              weights to TP-only layout once per step
-    #                              (ZeRO-1-for-compute; needs params*2/TP
-    #                              bytes of HBM), reduce-scatter grads back
-    remat_policy: str = "nothing"  # "nothing" | "dots" — remat checkpoint
-    #                              policy (dots saves matmul outputs:
-    #                              less recompute, more activation HBM)
-    causal_slice: bool = False   # triangle-sliced chunked attention in the
-    #                              unrolled path (flash-kernel block-skip
-    #                              analogue; ~2x attention flops saving)
 
     @property
     def hd(self) -> int:
@@ -234,27 +212,6 @@ def mlp_forward(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     return jnp.dot(h, p["w_down"])
 
 
-def map_token_chunks(fn: Callable, rows, chunk: int):
-    """``fn(rows)`` over row chunks of at most ``chunk`` rows.
-
-    ``rows`` is a pytree of arrays that share their leading (token) axis;
-    ``fn`` maps such a pytree to one array with that leading axis.  Above
-    ``chunk`` rows the tokens run through ``lax.map`` in equal chunks
-    (zero rows pad the last one and are dropped), so the intermediates of
-    a wide layer hold ``chunk`` rows at a time, not every token's."""
-    t = jax.tree.leaves(rows)[0].shape[0]
-    if t <= chunk:
-        return fn(rows)
-    n = -(-t // chunk)
-    c = -(-t // n)
-
-    def split(a):
-        a = jnp.pad(a, ((0, n * c - t),) + ((0, 0),) * (a.ndim - 1))
-        return a.reshape(n, c, *a.shape[1:])
-    out = jax.lax.map(fn, jax.tree.map(split, rows))
-    return out.reshape(n * c, *out.shape[2:])[:t]
-
-
 # ---------------------------------------------------------------------------
 # Stacking helpers (scan over layers)
 # ---------------------------------------------------------------------------
@@ -279,11 +236,7 @@ def stacked_axes(axes_one: dict) -> dict:
 def maybe_remat(fn: Callable, cfg: ModelConfig) -> Callable:
     if not cfg.remat:
         return fn
-    policy = {
-        "nothing": jax.checkpoint_policies.nothing_saveable,
-        "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    }[cfg.remat_policy]
-    return jax.checkpoint(fn, policy=policy)
+    return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
 
 
 def scan_or_unroll(body: Callable, carry, xs, use_scan: bool):

@@ -55,10 +55,11 @@ def cache_len(max_len: int) -> int:
     return -(-max_len // CACHE_BLOCK) * CACHE_BLOCK
 
 
-def params(key, cfg: ModelConfig) -> dict:
+def params(keys, cfg: ModelConfig) -> dict:
+    """The layer's attention weights, drawn from ``keys[0]``."""
     d, h, nope, rope, r, v = _dims(cfg)
     dt = cfg.dtype
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(keys[0], 4)
     return {
         "wq": cm.dense_init(ks[0], (d, h, nope + rope), dt),
         "wkv_a": cm.dense_init(ks[1], (d, r + rope), dt),
@@ -75,13 +76,10 @@ def specs(cfg: ModelConfig) -> dict:
             "kv_norm": s(r), "wkv_b": s(r, h, nope + v), "wo": s(h, v, d)}
 
 
-AXES = {
-    "wq": ("embed", "heads", None),
-    "wkv_a": ("embed", None),
-    "kv_norm": (None,),
-    "wkv_b": (None, "heads", None),
-    "wo": ("heads", None, "embed"),
-}
+def axes(cfg: ModelConfig) -> dict:
+    return {"wq": ("embed", "heads", None), "wkv_a": ("embed", None),
+            "kv_norm": (None,), "wkv_b": (None, "heads", None),
+            "wo": ("heads", None, "embed")}
 
 
 def _rope(x, positions, theta):
@@ -105,7 +103,8 @@ def _project(cfg: ModelConfig, p: dict, h, positions):
 
 def attend(cfg: ModelConfig, p: dict, h, positions):
     """Causal latent attention of ``h`` (B, S, D) over itself, keys and
-    values decompressed.  Returns (output (B, S, D), c_kv, k_pe)."""
+    values decompressed.  Returns (output (B, S, D), the layer's cache
+    rows ``{"ckv": c_kv (B, S, R), "kpe": k_pe (B, P, S)}``)."""
     b, s, _ = h.shape
     nope, heads = cfg.qk_nope_head_dim, cfg.n_heads
     q_nope, q_pe, c, k_pe = _project(cfg, p, h, positions)
@@ -118,7 +117,17 @@ def attend(cfg: ModelConfig, p: dict, h, positions):
     chunk = s if s <= 128 else math.gcd(s, 128)
     o = attn.multi_head_attention(q, k, kv[..., nope:], causal=True,
                                   chunk_q=chunk, scale=scale(cfg))
-    return jnp.einsum("bshv,hvd->bsd", o, p["wo"]), c, k_pe
+    return (jnp.einsum("bshv,hvd->bsd", o, p["wo"]),
+            {"ckv": c, "kpe": k_pe.swapaxes(1, 2)})
+
+
+def write(cache: dict, rows: dict, layer, pos) -> dict:
+    """``rows`` (as ``attend`` returns them) into layer ``layer`` of the
+    stacked caches, from position ``pos`` on."""
+    return {"ckv": jax.lax.dynamic_update_slice(
+                cache["ckv"], rows["ckv"][None], (layer, 0, pos, 0)),
+            "kpe": jax.lax.dynamic_update_slice(
+                cache["kpe"], rows["kpe"][None], (layer, 0, 0, pos))}
 
 
 def _latent_attention(q_lat, q_pe, ckv, kpe, layer, length, sc):
@@ -134,20 +143,32 @@ def _latent_attention(q_lat, q_pe, ckv, kpe, layer, length, sc):
     return o[:, 0].astype(q_lat.dtype)
 
 
-def decode(cfg: ModelConfig, p: dict, h, ckv, kpe, layer, pos):
+def decode(cfg: ModelConfig, p: dict, h, cache: dict, layer, pos):
     """One new position per sequence: ``h`` (B, 1, D) at position
     ``pos``.  Writes its latent row into layer ``layer`` of the stacked
     caches, attends positions ``0..pos`` in latent space, and returns
-    (output (B, 1, D), ckv, kpe)."""
+    (output (B, 1, D), cache)."""
     nope = cfg.qk_nope_head_dim
     q_nope, q_pe, c, k_pe = _project(cfg, p, h, jnp.reshape(pos, (1,)))
-    ckv = jax.lax.dynamic_update_slice(
-        ckv, c[None].astype(ckv.dtype), (layer, 0, pos, 0))
-    kpe = jax.lax.dynamic_update_slice(
-        kpe, k_pe.swapaxes(1, 2)[None].astype(kpe.dtype), (layer, 0, 0, pos))
+    cache = write(cache, {"ckv": c, "kpe": k_pe.swapaxes(1, 2)}, layer, pos)
     w_uk, w_uv = p["wkv_b"][..., :nope], p["wkv_b"][..., nope:]
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
-    o_lat = _latent_attention(q_lat, q_pe[:, 0], ckv, kpe, layer, pos + 1,
-                              scale(cfg))
+    o_lat = _latent_attention(q_lat, q_pe[:, 0], cache["ckv"], cache["kpe"],
+                              layer, pos + 1, scale(cfg))
     o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv)
-    return jnp.einsum("bhv,hvd->bd", o, p["wo"])[:, None], ckv, kpe
+    return jnp.einsum("bhv,hvd->bd", o, p["wo"])[:, None], cache
+
+
+def cache_specs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    """Latent rows ``(L, B, T, R)`` and rope keys ``(L, B, P, T)``, ``T``
+    the kernel's block multiple (``cache_len``)."""
+    t, lb = cache_len(max_len), (cfg.n_layers, batch)
+    return {"ckv": jax.ShapeDtypeStruct(lb + (t, cfg.kv_lora_rank),
+                                        cfg.dtype),
+            "kpe": jax.ShapeDtypeStruct(lb + (cfg.qk_rope_head_dim, t),
+                                        cfg.dtype)}
+
+
+def cache_axes(cfg: ModelConfig) -> dict:
+    return {"ckv": ("layer", "batch", "kv_seq", None),
+            "kpe": ("layer", "batch", None, "kv_seq")}

@@ -1,9 +1,10 @@
 """Mesh context threaded through model code.
 
 Model forward functions are mesh-agnostic except for explicitly
-communication-aware blocks (MoE expert parallelism, sequence-sharded
-decode).  Those consult the active mesh set by the step builders /
-launchers via ``use_mesh``.
+communication-aware blocks (MoE expert parallelism) and two choices made
+from the mesh (a long prefill's tokens per pass, the dense decode
+cache's layout pin).  Those consult the active mesh set by the step
+builders / launchers via ``use_mesh``.
 """
 from __future__ import annotations
 
@@ -36,8 +37,7 @@ def use_mesh(mesh):
 
 def set_unroll(flag: bool) -> None:
     """Counting mode: unroll inner (chunk) loops so XLA cost_analysis sees
-    every iteration (while-loop bodies are counted once — verified in
-    EXPERIMENTS.md §Dry-run methodology)."""
+    every iteration (it counts a while loop's body once)."""
     _STATE.unroll = bool(flag)
 
 

@@ -52,21 +52,6 @@ def build_train_step(cfg: ModelConfig, *, n_microbatch: int = 1,
         assert b % n_microbatch == 0, (b, n_microbatch)
         mb = b // n_microbatch
 
-        # §Perf gather-weights-once: hoist the FSDP all-gather out of the
-        # microbatch/remat passes (baseline re-gathers every pass).
-        # Compute runs on a TP-only layout; gradients reduce-scatter back
-        # to the FSDP layout before the optimizer.
-        compute_params = params
-        if cfg.gather_weights_once and pctx.get_mesh() is not None:
-            mesh = pctx.get_mesh()
-            rules = dict(shd.DEFAULT_RULES)
-            rules["embed"] = None          # drop the FSDP dim
-            rules["expert_mlp"] = None
-            axes = registry.logical_axes(cfg)
-            g_sh = shd.shardings_from_axes(axes, mesh, rules, params)
-            compute_params = jax.tree.map(
-                jax.lax.with_sharding_constraint, params, g_sh)
-
         def resh(x):
             y = x.reshape(n_microbatch, mb, *x.shape[1:])
             mesh = pctx.get_mesh()
@@ -92,7 +77,7 @@ def build_train_step(cfg: ModelConfig, *, n_microbatch: int = 1,
             fe = mbx.get("frontend_embeds")
             (_, ce), grads = jax.value_and_grad(
                 lambda p: _loss_fn(cfg, p, mbx["tokens"], mbx["labels"],
-                                   fe), has_aux=True)(compute_params)
+                                   fe), has_aux=True)(params)
             gacc = jax.tree.map(
                 lambda a, g: a + g.astype(jnp.float32), gacc, grads)
             return (gacc, lacc + ce), None

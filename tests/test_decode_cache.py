@@ -19,7 +19,7 @@ import pytest
 from repro import configs
 from repro.models import attention as attn
 from repro.models import common as cm
-from repro.models import mla, registry, transformer
+from repro.models import gqa, mla, registry, transformer
 from repro.serve.loop import BatchServer
 
 #: a dense GQA decoder and a latent-attention expert model
@@ -65,9 +65,10 @@ def _xs_ys_step(cfg, params, token, cache):
     def latent(xc, layer_in):
         lp, ckv, kpe = layer_in
         h = cm.rms_norm(xc, lp["ln1"], cfg.norm_eps)
-        o, ckv, kpe = mla.decode(cfg, lp, h, ckv[None], kpe[None], 0, pos)
+        o, c = mla.decode(cfg, lp, h, {"ckv": ckv[None], "kpe": kpe[None]},
+                          0, pos)
         xc, _ = transformer._ffn_block(cfg, lp, xc + o)
-        return xc, (ckv[0], kpe[0])
+        return xc, (c["ckv"][0], c["kpe"][0])
 
     keys = ("ckv", "kpe") if cfg.kv_lora_rank else ("k", "v")
     first, out = 0, []
@@ -167,3 +168,76 @@ def test_dense_cache_layout_and_decode_match_forward(arch):
                                    np.asarray(full[:, s + i]),
                                    rtol=1e-4, atol=1e-4)
     assert int(cache["len"]) == s + STEPS
+
+
+def _gqa_expected(cfg, lp, h, cache, layer, pos):
+    """Grouped-query decode over the rows before ``pos`` and the new one."""
+    q, k, v = gqa._project(cfg, lp, h, jnp.reshape(pos, (1,)))
+    ks, vs = (cache[n][layer].swapaxes(1, 2)[:, :pos + 1].at[:, pos].set(
+        new[:, 0]) for n, new in (("k", k), ("v", v)))
+    o = attn.decode_attention(q, ks, vs, pos + 1)
+    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), {"k": k, "v": v}
+
+
+def _mla_expected(cfg, lp, h, cache, layer, pos):
+    """Absorbed latent decode over the rows before ``pos`` and the new
+    one: one KV head of ``[c_kv || k_pe]``, values ``c_kv``."""
+    nope = cfg.qk_nope_head_dim
+    q_nope, q_pe, c, k_pe = mla._project(cfg, lp, h, jnp.reshape(pos, (1,)))
+    cs = cache["ckv"][layer][:, :pos + 1].at[:, pos].set(c[:, 0])
+    pes = cache["kpe"][layer].swapaxes(1, 2)[:, :pos + 1].at[:, pos].set(
+        k_pe[:, 0])
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], lp["wkv_b"][..., :nope])
+    q = jnp.concatenate([q_lat, q_pe[:, 0]], -1)[:, None]
+    k = jnp.concatenate([cs, pes], -1)[:, :, None]
+    o = attn.decode_attention(q, k, cs[:, :, None], pos + 1,
+                              scale=mla.scale(cfg))[:, 0]
+    o = jnp.einsum("bhr,rhv->bhv", o, lp["wkv_b"][..., nope:])
+    return (jnp.einsum("bhv,hvd->bd", o, lp["wo"])[:, None],
+            {"ckv": c, "kpe": k_pe})
+
+
+#: (module, smoke config, expected output and rows, where the new row of
+#: each cache array lies)
+_MODULES = {
+    "gqa": (gqa, "qwen3_4b", _gqa_expected,
+            lambda layer, pos: {"k": (layer, slice(None), slice(None), pos),
+                                "v": (layer, slice(None), slice(None), pos)}),
+    "mla": (mla, "moonshot_v1_16b_a3b", _mla_expected,
+            lambda layer, pos: {"ckv": (layer, slice(None), pos),
+                                "kpe": (layer, slice(None), slice(None),
+                                        pos)}),
+}
+
+
+@pytest.mark.parametrize("kind", list(_MODULES))
+def test_attention_decode_writes_only_its_row(kind):
+    """One ``decode`` at ``(layer, pos)`` into a cache filled with
+    garbage: every cache element but that layer's row ``pos`` is
+    bit-unchanged, the row holds the position's projection, and the
+    output is ``attention.decode_attention`` over positions ``<= pos``
+    (the garbage beyond them and in other layers is never read)."""
+    module, arch, expected, row_at = _MODULES[kind]
+    cfg = _config(arch, jnp.float32)
+    params = registry.init(cfg, jax.random.PRNGKey(0))
+    layer, pos, b = 1, 9, 2
+    lp = jax.tree.map(lambda a: a[layer - cfg.first_k_dense],
+                      params["layers"])
+    spec = module.cache_specs(cfg, b, 20)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(spec) + 1)
+    cache = {n: 1e3 * jax.random.normal(k, a.shape, a.dtype)
+             for k, (n, a) in zip(keys, sorted(spec.items()))}
+    h = jax.random.normal(keys[-1], (b, 1, cfg.d_model), jnp.float32)
+
+    out, new = jax.jit(lambda c: module.decode(cfg, lp, h, c, layer, pos))(
+        dict(cache))
+    want, rows = expected(cfg, lp, h, cache, layer, pos)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for n, at in row_at(layer, pos).items():
+        got, old = np.asarray(new[n]), np.asarray(cache[n])
+        keep = np.ones(old.shape, bool)
+        keep[at] = False
+        np.testing.assert_array_equal(got[keep], old[keep])
+        np.testing.assert_allclose(got[at], np.asarray(rows[n][:, 0]),
+                                   rtol=1e-6, atol=1e-6)

@@ -134,19 +134,22 @@ def test_absorbed_decode_equals_decompressed_attention(model):
     lp = jax.tree.map(lambda a: a[0], params["layers"])
     s, b = 9, 3
     h = jax.random.normal(jax.random.PRNGKey(0), (b, s, 64), jnp.float32)
-    full, c, k_pe = mla.attend(mc, lp, h, jnp.arange(s))
+    full, rows = mla.attend(mc, lp, h, jnp.arange(s))
+    c, k_pe = rows["ckv"], rows["kpe"]
     spec = registry.cache_specs(mc, b, s)
     ckv = jnp.zeros(spec["ckv"].shape, jnp.float32).at[1, :, :s - 1].set(
         c[:, :s - 1])
     kpe = jnp.zeros(spec["kpe"].shape, jnp.float32).at[1, :, :, :s - 1].set(
-        k_pe[:, :s - 1].swapaxes(1, 2))
-    out, ckv, kpe = mla.decode(mc, lp, h[:, s - 1:], ckv, kpe, 1, s - 1)
+        k_pe[:, :, :s - 1])
+    out, cache = mla.decode(mc, lp, h[:, s - 1:], {"ckv": ckv, "kpe": kpe},
+                            1, s - 1)
+    ckv, kpe = cache["ckv"], cache["kpe"]
     np.testing.assert_allclose(np.asarray(out[:, 0]),
                                np.asarray(full[:, s - 1]), **F32_TOL)
     np.testing.assert_allclose(np.asarray(ckv[1, :, :s]), np.asarray(c),
                                **F32_TOL)
     np.testing.assert_allclose(np.asarray(kpe[1, :, :, :s]),
-                               np.asarray(k_pe.swapaxes(1, 2)), **F32_TOL)
+                               np.asarray(k_pe), **F32_TOL)
     assert not np.asarray(ckv[0]).any() and not np.asarray(ckv[2]).any()
 
 
@@ -227,9 +230,11 @@ def test_no_slot_is_dropped(tokens, skew):
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(_dense_held(mc, p, xt, ids, w)),
                                **F32_TOL)
-    # in chunks, as a long prefill runs, the same
-    chunked = cm.map_token_chunks(
-        lambda r: moe.held_experts(mc, p, *r), (xt, ids, w), 3)
+    # in spans of the tokens, as a long prefill runs, the same
+    span = max(tokens // 3, 1)
+    chunked = jnp.concatenate([
+        moe.held_experts(mc, p, xt[i:i + span], ids[i:i + span],
+                         w[i:i + span]) for i in range(0, tokens, span)])
     np.testing.assert_allclose(np.asarray(chunked), np.asarray(got),
                                **F32_TOL)
 
