@@ -72,6 +72,7 @@ SEND_OVERHEAD_NS = int(_SCHED_DEFAULTS["send_overhead_ns"])
 PREEMPT_AFTER = int(_SCHED_DEFAULTS["preempt_after"])
 
 _INF = ej.INF_TICKS
+_I64_MAX = int(np.iinfo(np.int64).max)
 
 
 class UnsupportedByEngine(ValueError):
@@ -100,7 +101,6 @@ class _Msg:
     ch2: int
     ser2: int               # ns
     lat2: int               # ns
-    extras: List[Tuple[int, int]]   # (from_vtime ns, extra ns)
 
 
 @dataclasses.dataclass
@@ -123,12 +123,14 @@ class CompiledSim:
     hub_base: str                   # multi-host hub name prefix
     n_hosts: int
     scenario_name: str
-    #: additive ns quantities (for sweep: shared-tick computation)
-    quantities: List[int]
+    #: additive ns quantities, int64 (for sweep: shared-tick computation)
+    quantities: np.ndarray
+    #: the lowered structure it was quantized from (see :func:`_lower`)
+    structure: Dict[str, Any] = dataclasses.field(repr=False)
 
 
 # ---------------------------------------------------------------------------
-# lowering: facade -> ns-level tapes
+# lowering: facade -> ns-level arrays
 # ---------------------------------------------------------------------------
 
 
@@ -149,22 +151,13 @@ def _detect_cells(sim, programs, inter_targets) -> bool:
     return bool(cell_of) or any(c is not None for c in load_cells)
 
 
-def _lower(sim) -> Dict[str, Any]:
-    """Validate the scenario against the admissible surface and lower
-    it to ns-level python/numpy structures (tick-independent)."""
-    topo = sim.topology
-    programs = sim._programs()
-    fabrics = sim._fabrics()
-    names = [p.name for _, p in programs]
-    placement = sim._resolve_placement(names)
-    sim.placement = placement
-    inter_targets = sim._resolve_interference()
-
+def _check_surface(sim) -> None:
+    """The admissibility checks a scenario's injections can fail."""
     if sim.cpu_resource:
         raise UnsupportedByEngine(
             "cpu_resource=True: CPU-slot contention is an engine "
             "schedule, not an array op")
-    if getattr(topo, "joins", None) or any(
+    if getattr(sim.topology, "joins", None) or any(
             isinstance(inj, JoinHost) for inj in sim.scenario.injections):
         raise UnsupportedByEngine(
             "membership joins: late hosts need the conservative "
@@ -182,6 +175,54 @@ def _lower(sim) -> Dict[str, Any]:
             raise UnsupportedByEngine(
                 "ClockSkew: ingress hooks are per-delivery hub state, "
                 "not a tape-time transform")
+
+
+def _shares_structure(sim, low: Dict[str, Any]) -> bool:
+    """Whether ``sim`` lowers to the structure of ``low``: the same
+    topology, workloads and placement (as a sweep's variants have) and
+    an equal resolved Interference list.  Sets ``sim.placement`` as
+    :func:`_lower` does."""
+    st = low["structure"]
+    workloads, spec = st["owner"]
+    if not (sim.topology is st["topology"]
+            and len(sim.workloads) == len(workloads)
+            and all(a is b for a, b in zip(sim.workloads, workloads))
+            and (sim.placement_spec, sim.capacity, sim.cpu_resource,
+                 sim.cells_mode) == spec):
+        return False
+    sim.placement = dict(st["placement"])
+    return sim._resolve_interference() == st["inter_targets"]
+
+
+def _lower(sim, shared: Optional[Dict[str, Any]] = None
+           ) -> Dict[str, Any]:
+    """Validate the scenario against the admissible surface and lower
+    it to ns-level arrays (tick-independent).  A lowering is the
+    scenario's ``structure`` — tapes, messages, channels, scopes, and
+    the unscaled compute ns per tape position — which its injections
+    shape only through the resolved Interference list, plus the values
+    its other injections set: Straggler-scaled compute ns, fail points
+    and DegradeLink extras.  ``shared`` is the lowering of another
+    variant of the same Simulation (a sweep's first); when ``sim`` has
+    its structure (:func:`_shares_structure`) only the values are
+    lowered, after the same checks on them."""
+    if shared is not None and _shares_structure(sim, shared):
+        st = shared["structure"]
+        _check_surface(sim)
+        scale, fails = sim._resolve_fault_plan(st["names"])
+        comp_ns = _scaled_ns(st, scale)
+        _check_progress(st, comp_ns)
+        return _values(sim, st, comp_ns, fails)
+
+    topo = sim.topology
+    programs = sim._programs()
+    fabrics = sim._fabrics()
+    names = [p.name for _, p in programs]
+    placement = sim._resolve_placement(names)
+    sim.placement = placement
+    inter_targets = sim._resolve_interference()
+
+    _check_surface(sim)
     for _, p in programs:
         if p.kind != "modeled":
             raise UnsupportedByEngine(
@@ -212,68 +253,87 @@ def _lower(sim) -> Dict[str, Any]:
     # endpoints (mirrors the build() spawn loop's wiring checks)
     ep_owner: Dict[str, str] = {}
     ep_fabric: Dict[str, str] = {}
-    fabric_by_name = {f.name: f for f in fabrics}
+    fabric_idx = {f.name: i for i, f in enumerate(fabrics)}
     for _, p in programs:
         for es in p.endpoints:
             if es.name in ep_owner:
                 raise ValueError(f"duplicate endpoint {es.name!r}")
-            if es.fabric not in fabric_by_name:
+            if es.fabric not in fabric_idx:
                 raise KeyError(f"unknown fabric {es.fabric!r}")
             ep_owner[es.name] = p.name
             ep_fabric[es.name] = es.fabric
 
     scale, fails = sim._resolve_fault_plan(names)
 
-    # task list: programs (report-visible) then interference loads
-    tapes: List[list] = []        # per task: real ops (marks stripped)
+    # task list: programs (report-visible) then interference loads; each
+    # task's real ops (marks stripped) as positions of their kind
     markers: List[List[Tuple[int, int, str, int, int]]] = []
     task_names: List[str] = []
     task_hosts: List[int] = []
-    for _, p in programs:
-        factor = scale.get(p.name)
-        real: list = []
+    n_ops: List[int] = []
+    comp_rows: List[int] = []
+    comp_cols: List[int] = []
+    comp_ns: List[int] = []
+    sends: List[Tuple[int, int, VecSend]] = []
+    recvs: List[Tuple[int, int, VecRecv]] = []
+    for t, (_, p) in enumerate(programs):
+        j = 0
         marks: List[Tuple[int, int, str, int, int]] = []
         for op in ops_by_name[p.name]:
             if isinstance(op, VecMark):
-                marks.append((len(real), wl_of_prog[p.name],
+                marks.append((j, wl_of_prog[p.name],
                               op.array, op.index, op.value))
                 continue
             if isinstance(op, VecCompute):
-                ns = int(op.ns * factor) if factor is not None else op.ns
-                real.append(VecCompute(ns))
+                comp_rows.append(t)
+                comp_cols.append(j)
+                comp_ns.append(op.ns)
             elif isinstance(op, (VecSend, VecRecv)):
                 if ep_owner.get(op.endpoint) != p.name:
                     raise ValueError(
                         f"program {p.name!r} uses endpoint "
                         f"{op.endpoint!r} it does not own")
-                real.append(op)
+                (sends if isinstance(op, VecSend) else recvs).append(
+                    (t, j, op))
             else:
                 raise UnsupportedByEngine(
                     f"program {p.name!r}: op {op!r} has no vectorized "
                     f"form")
-        tapes.append(real)
+            j += 1
+        n_ops.append(j)
         markers.append(marks)
         task_names.append(p.name)
         task_hosts.append(placement[p.name])
     n_programs = len(programs)
     for i, (inj, host) in enumerate(inter_targets):
-        tapes.append([VecCompute(inj.burst_ns)] * inj.bursts)
+        bursts = range(inj.bursts)
+        comp_rows.extend([len(n_ops)] * len(bursts))
+        comp_cols.extend(bursts)
+        comp_ns.extend([inj.burst_ns] * len(bursts))
+        n_ops.append(len(bursts))
         markers.append([])
         task_names.append(f"load{i}")
         task_hosts.append(host)
-    n_tasks = len(tapes)
-    for name, real in zip(task_names, tapes):
-        # the reference counter resets on *progress*, so interleaved
-        # sends/recvs don't break a zero-compute run
-        zero_run = 0
-        for op in real:
-            if isinstance(op, VecCompute):
-                zero_run = zero_run + 1 if op.ns <= 0 else 0
-                if zero_run >= PREEMPT_AFTER:
-                    raise UnsupportedByEngine(
-                        f"task {name!r}: >= {PREEMPT_AFTER} "
-                        f"consecutive zero-progress computes — the "
-                        f"reference scheduler would preempt it FAULTY")
+    n_tasks = len(n_ops)
+    rows = np.array(comp_rows, np.int64)
+    st: Dict[str, Any] = dict(
+        owner=(tuple(sim.workloads),
+               (sim.placement_spec, sim.capacity, sim.cpu_resource,
+                sim.cells_mode)),
+        placement=placement, inter_targets=inter_targets, names=names,
+        name_idx={n: i for i, n in enumerate(names)},
+        task_names=task_names, task_hosts=task_hosts,
+        n_programs=n_programs, markers=markers,
+        n_ops=np.array(n_ops, np.int32), comp_rows=rows,
+        comp_cols=np.array(comp_cols, np.int64),
+        comp_ns=np.array(comp_ns, np.int64),
+        comp_off=np.concatenate(
+            ([0], np.cumsum(np.bincount(rows, minlength=n_tasks)))),
+        topology=topo, fabrics=fabrics, fabric_idx=fabric_idx,
+        hub_base=fabrics[0].name if fabrics else "hub",
+        n_hosts=topo.n_hosts)
+    scaled = _scaled_ns(st, scale)
+    _check_progress(st, scaled)
 
     # messages + channels.  Pass 1: sends, in task/program order (=
     # per-channel FIFO order); pass 2: receive matching.
@@ -283,46 +343,43 @@ def _lower(sim) -> Dict[str, Any]:
         return channels.setdefault(key, len(channels))
 
     msgs: List[_Msg] = []
+    cols: List[tuple] = []      # per message: its numeric fields
     sends_to: Dict[str, List[int]] = {}
     dst_sources: Dict[str, set] = {}
     peer_producers: Dict[tuple, set] = {}
-    send_arg: Dict[Tuple[int, int], int] = {}
-    for t, ops in enumerate(tapes):
-        for j, op in enumerate(ops):
-            if not isinstance(op, VecSend):
-                continue
-            if op.dst not in ep_owner:
-                raise KeyError(f"unknown endpoint {op.dst!r}")
-            fs, fd = ep_fabric[op.endpoint], ep_fabric[op.dst]
-            if fs != fd:
-                raise UnsupportedByEngine(
-                    f"cross-fabric send {op.endpoint!r}->{op.dst!r} "
-                    f"({fs!r} vs {fd!r})")
-            flink = fabric_by_name[fs].link
-            sh = placement[ep_owner[op.endpoint]]
-            dh = placement[ep_owner[op.dst]]
-            if sh == dh:
-                m = _Msg(op.endpoint, op.dst, op.size_bytes, t, sh, dh,
-                         ch1=chan(("ep", op.endpoint, op.dst)),
-                         ser1=_ser_ns(op.size_bytes, flink),
-                         lat1=flink.latency_ns, two_stage=False,
-                         ch2=0, ser2=0, lat2=0, extras=[])
-            else:
-                plink = topo.host_link(sh, dh)
-                key = ("peer", sh, dh)
-                peer_producers.setdefault(key, set()).add(t)
-                m = _Msg(op.endpoint, op.dst, op.size_bytes, t, sh, dh,
-                         ch1=chan(key),
-                         ser1=_ser_ns(op.size_bytes, plink),
-                         lat1=plink.latency_ns, two_stage=True,
-                         ch2=chan(("ep", op.endpoint, op.dst)),
-                         ser2=_ser_ns(op.size_bytes, flink),
-                         lat2=flink.latency_ns, extras=[])
-            mid = len(msgs)
-            msgs.append(m)
-            send_arg[(t, j)] = mid
-            sends_to.setdefault(op.dst, []).append(mid)
-            dst_sources.setdefault(op.dst, set()).add(op.endpoint)
+    for t, _, op in sends:
+        if op.dst not in ep_owner:
+            raise KeyError(f"unknown endpoint {op.dst!r}")
+        fs, fd = ep_fabric[op.endpoint], ep_fabric[op.dst]
+        if fs != fd:
+            raise UnsupportedByEngine(
+                f"cross-fabric send {op.endpoint!r}->{op.dst!r} "
+                f"({fs!r} vs {fd!r})")
+        flink = fabrics[fabric_idx[fs]].link
+        sh = placement[ep_owner[op.endpoint]]
+        dh = placement[ep_owner[op.dst]]
+        if sh == dh:
+            m = _Msg(op.endpoint, op.dst, op.size_bytes, t, sh, dh,
+                     ch1=chan(("ep", op.endpoint, op.dst)),
+                     ser1=_ser_ns(op.size_bytes, flink),
+                     lat1=flink.latency_ns, two_stage=False,
+                     ch2=0, ser2=0, lat2=0)
+        else:
+            plink = topo.host_link(sh, dh)
+            key = ("peer", sh, dh)
+            peer_producers.setdefault(key, set()).add(t)
+            m = _Msg(op.endpoint, op.dst, op.size_bytes, t, sh, dh,
+                     ch1=chan(key),
+                     ser1=_ser_ns(op.size_bytes, plink),
+                     lat1=plink.latency_ns, two_stage=True,
+                     ch2=chan(("ep", op.endpoint, op.dst)),
+                     ser2=_ser_ns(op.size_bytes, flink),
+                     lat2=flink.latency_ns)
+        sends_to.setdefault(op.dst, []).append(len(msgs))
+        dst_sources.setdefault(op.dst, set()).add(op.endpoint)
+        msgs.append(m)
+        cols.append((m.ch1, m.ser1, m.lat1, m.two_stage, m.ch2, m.ser2,
+                     m.lat2, sh, dh, fabric_idx[fs]))
     n_msgs = len(msgs)
     multi = sorted(ep for ep, srcs in dst_sources.items()
                    if len(srcs) > 1)
@@ -338,24 +395,105 @@ def _lower(sim) -> Dict[str, Any]:
             f"host pairs {multi_peer} carry cross-host sends from "
             f"multiple producer tasks: peer-channel FIFO order would "
             f"depend on the engine schedule")
-    recv_arg: Dict[Tuple[int, int], int] = {}
+    recv_arg: List[int] = []
     recv_count: Dict[str, int] = {}
-    for t, ops in enumerate(tapes):
-        for j, op in enumerate(ops):
-            if not isinstance(op, VecRecv):
-                continue
-            k = recv_count.get(op.endpoint, 0)
-            recv_count[op.endpoint] = k + 1
-            matched = sends_to.get(op.endpoint, [])
-            # unmatched -> the never-sent sentinel row (blocks forever)
-            recv_arg[(t, j)] = matched[k] if k < len(matched) else n_msgs
+    for _, _, op in recvs:
+        k = recv_count.get(op.endpoint, 0)
+        recv_count[op.endpoint] = k + 1
+        matched = sends_to.get(op.endpoint, [])
+        # unmatched -> the never-sent sentinel row (blocks forever)
+        recv_arg.append(matched[k] if k < len(matched) else n_msgs)
 
-    # DegradeLink hooks -> per-message (from_vtime, extra) pairs
-    # (sender-side stage-1 only, exactly like Hub.route's hook pass)
-    fabric_eps: Dict[str, List[str]] = {f.name: [] for f in fabrics}
-    for _, p in programs:
-        for es in p.endpoints:
-            fabric_eps[es.fabric].append(es.name)
+    # the tape arrays: op kinds, and each send's and receive's message
+    p = max(1, max(n_ops, default=0))
+    op_kind = np.zeros((n_tasks, p), np.int32)
+    op_arg = np.zeros((n_tasks, p), np.int32)
+    op_kind[rows, st["comp_cols"]] = ej.OP_COMPUTE
+    for kind, pos, args in ((ej.OP_SEND, sends, np.arange(n_msgs)),
+                            (ej.OP_RECV, recvs, recv_arg)):
+        rc = np.array([(t, j) for t, j, _ in pos], np.int64).reshape(-1, 2)
+        op_kind[rc[:, 0], rc[:, 1]] = kind
+        op_arg[rc[:, 0], rc[:, 1]] = args
+    (ch1, ser1, lat1, two, ch2, ser2, lat2, src_host, dst_host,
+     msg_fabric) = np.array(cols, np.int64).reshape(-1, 10).T
+    two = two.astype(bool)
+
+    # scopes (loads never join)
+    name_idx = st["name_idx"]
+    scope_skews: List[int] = []
+    names_by_wl: Dict[int, List[str]] = {}
+    for wl, prog in programs:
+        names_by_wl.setdefault(id(wl), []).append(prog.name)
+    scope_members: List[List[int]] = []
+    for wl in sim.workloads:
+        wl_names = names_by_wl.get(id(wl), [])
+        for ss in wl.scopes():
+            scope_members.append([name_idx[m] for m in
+                                  (ss.members or tuple(wl_names))])
+            scope_skews.append(ss.skew_bound_ns)
+    membership = np.zeros((n_tasks, len(scope_members)), bool)
+    for j, members in enumerate(scope_members):
+        membership[members, j] = True
+
+    st.update(
+        op_kind=op_kind, op_arg=op_arg, msgs=msgs,
+        n_channels=len(channels), msg_ch1=ch1, msg_ser1=ser1,
+        msg_lat1=lat1, msg_two_stage=two, msg_ch2=ch2, msg_ser2=ser2,
+        msg_lat2=lat2, msg_src_host=src_host, msg_dst_host=dst_host,
+        msg_fabric=msg_fabric,
+        msg_qs=np.concatenate((np.full(n_msgs, SEND_OVERHEAD_NS, np.int64),
+                               ser1, lat1, ser2[two], lat2[two])),
+        membership=membership, scope_skews=scope_skews)
+    return _values(sim, st, scaled, fails)
+
+
+def _scaled_ns(st: Dict[str, Any], scale: Dict[str, float]) -> np.ndarray:
+    """Compute ns per tape position, each program's scaled by its
+    Straggler factor as the reference engines scale (``int(ns *
+    factor)``: a float64 product, truncated), always from the unscaled
+    ns."""
+    ns = st["comp_ns"]
+    if not scale:
+        return ns
+    ns = ns.copy()
+    off = st["comp_off"]
+    for name, factor in scale.items():
+        i = st["name_idx"][name]
+        a, b = off[i], off[i + 1]
+        prod = st["comp_ns"][a:b] * factor
+        if not (np.abs(prod) < 2.0 ** 63).all():
+            raise ej.TickRangeError(
+                f"Straggler {name!r} x{factor}: compute ns beyond int64")
+        ns[a:b] = prod.astype(np.int64)
+    return ns
+
+
+def _check_progress(st: Dict[str, Any], comp_ns: np.ndarray) -> None:
+    """Refuse a task that the reference would preempt: ``PREEMPT_AFTER``
+    consecutive zero-progress computes.  The reference counter resets on
+    *progress*, so interleaved sends/recvs don't break a zero run."""
+    zero = comp_ns <= 0
+    if not zero.any():
+        return
+    off = st["comp_off"]
+    for t in np.unique(st["comp_rows"][zero]):
+        run = 0
+        for z in zero[off[t]:off[t + 1]]:
+            run = run + 1 if z else 0
+            if run >= PREEMPT_AFTER:
+                raise UnsupportedByEngine(
+                    f"task {st['task_names'][t]!r}: >= {PREEMPT_AFTER} "
+                    f"consecutive zero-progress computes — the "
+                    f"reference scheduler would preempt it FAULTY")
+
+
+def _values(sim, st: Dict[str, Any], comp_ns: np.ndarray,
+            fails: Dict[str, FailTask]) -> Dict[str, Any]:
+    """A lowering: the structure ``st`` with the scenario's values."""
+    # DegradeLink hooks -> per-message (from_vtime, extra), as a mask of
+    # the messages each adds to (sender-side stage-1 only, exactly like
+    # Hub.route's hook pass)
+    degrades: List[Tuple[np.ndarray, int, int]] = []
     for inj in sim.scenario.injections:
         if not isinstance(inj, DegradeLink):
             continue
@@ -363,87 +501,55 @@ def _lower(sim) -> Dict[str, Any]:
             raise ValueError("DegradeLink needs exactly one of "
                              "fabric= or hosts=")
         if inj.fabric is not None:
-            fab = fabric_by_name.get(inj.fabric)
-            if fab is None:
+            fi = st["fabric_idx"].get(inj.fabric)
+            if fi is None:
                 raise ValueError(f"unknown fabric {inj.fabric!r}")
-            members = set(fabric_eps[inj.fabric])
             extra = inj.extra_ns + int(
-                (inj.latency_factor - 1.0) * fab.link.latency_ns)
-
-            def match(m: _Msg) -> bool:
-                return m.src_ep in members and m.dst_ep in members
+                (inj.latency_factor - 1.0) *
+                st["fabrics"][fi].link.latency_ns)
+            match = st["msg_fabric"] == fi
         else:
             a, b = inj.hosts
-            pair_link = topo.host_link(a, b)
+            pair_link = st["topology"].host_link(a, b)
             extra = inj.extra_ns + int(
                 (inj.latency_factor - 1.0) * pair_link.latency_ns)
-
-            def match(m: _Msg, a=a, b=b) -> bool:
-                return {m.src_host, m.dst_host} == {a, b}
+            src, dst = st["msg_src_host"], st["msg_dst_host"]
+            match = ((src == a) & (dst == b)) | ((src == b) & (dst == a))
         if extra < 0:
             raise ValueError("DegradeLink may only add latency "
                              "(conservative lookahead)")
-        for m in msgs:
-            if match(m):
-                m.extras.append((inj.from_vtime, extra))
+        degrades.append((match, inj.from_vtime, extra))
 
     # fail points: at_compute -> tape index of the k-th (0-based)
     # compute op; at_vtime -> checked at every op boundary
-    fail_pc = [None] * n_tasks
-    fail_vt = [None] * n_tasks
-    for i, name in enumerate(task_names[:n_programs]):
-        f = fails.get(name)
-        if f is None:
-            continue
+    fail_pc: Dict[int, int] = {}
+    fail_vt: Dict[int, int] = {}
+    off = st["comp_off"]
+    for name, f in fails.items():
+        i = st["name_idx"][name]
         if f.at_vtime is not None:
             fail_vt[i] = f.at_vtime
-        if f.at_compute is not None:
-            k = 0
-            for j, op in enumerate(tapes[i]):
-                if isinstance(op, VecCompute):
-                    if k == f.at_compute:
-                        fail_pc[i] = j
-                        break
-                    k += 1
-
-    # scopes (loads never join)
-    name_idx = {n: i for i, n in enumerate(task_names[:n_programs])}
-    scope_members: List[List[int]] = []
-    scope_skews: List[int] = []
-    names_by_wl: Dict[int, List[str]] = {}
-    for wl, prog in programs:
-        names_by_wl.setdefault(id(wl), []).append(prog.name)
-    for wl in sim.workloads:
-        wl_names = names_by_wl.get(id(wl), [])
-        for ss in wl.scopes():
-            members = [name_idx[m]
-                       for m in (ss.members or tuple(wl_names))]
-            scope_members.append(members)
-            scope_skews.append(ss.skew_bound_ns)
-
-    return dict(tapes=tapes, markers=markers, task_names=task_names,
-                task_hosts=task_hosts, n_programs=n_programs,
-                msgs=msgs, n_channels=len(channels),
-                send_arg=send_arg, recv_arg=recv_arg,
-                scope_members=scope_members, scope_skews=scope_skews,
+        if f.at_compute is not None and \
+                0 <= f.at_compute < off[i + 1] - off[i]:
+            fail_pc[i] = int(st["comp_cols"][off[i] + f.at_compute])
+    return dict(structure=st, comp_ns=comp_ns, degrades=degrades,
                 fail_pc=fail_pc, fail_vt=fail_vt,
-                hub_base=fabrics[0].name if fabrics else "hub",
-                n_hosts=topo.n_hosts, scenario_name=sim.scenario.name)
+                scenario_name=sim.scenario.name)
 
 
-def _quantities(low: Dict[str, Any]) -> List[int]:
+def _quantities(low: Dict[str, Any]) -> np.ndarray:
     """Every additive ns quantity of the lowered scenario (each appears
     at most once on any event time's max-plus dependency path)."""
-    qs: List[int] = []
-    for ops in low["tapes"]:
-        qs.extend(op.ns for op in ops if isinstance(op, VecCompute))
-    for m in low["msgs"]:
-        qs.append(SEND_OVERHEAD_NS)
-        qs.extend((m.ser1, m.lat1))
-        if m.two_stage:
-            qs.extend((m.ser2, m.lat2))
-        qs.extend(e for _, e in m.extras)
-    return qs
+    return np.concatenate(
+        [low["comp_ns"], low["structure"]["msg_qs"]] +
+        [np.full(int(match.sum()), extra, np.int64)
+         for match, _, extra in low["degrades"]])
+
+
+def _gcd(qs: np.ndarray) -> int:
+    """The gcd of the positive quantities (0 when there are none)."""
+    pos = qs[qs > 0]
+    return int(np.gcd.reduce(pos)) if pos.size else 0
 
 
 # ---------------------------------------------------------------------------
@@ -451,109 +557,101 @@ def _quantities(low: Dict[str, Any]) -> List[int]:
 # ---------------------------------------------------------------------------
 
 
-def _quantize(low: Dict[str, Any],
-              tick_ns: Optional[int]) -> CompiledSim:
+def _quantize(low: Dict[str, Any], tick_ns: Optional[int],
+              shared: Optional[CompiledSim] = None) -> CompiledSim:
+    """Quantize a lowering to ticks.  ``shared`` is the quantization of
+    another lowering with the same structure (a sweep's first): at the
+    same tick its structural arrays are reused as they are, and only
+    this lowering's values — compute ticks, fail points, DegradeLink
+    extras — are made."""
+    st = low["structure"]
     qs = _quantities(low)
-    pos = [q for q in qs if q > 0]
+    gcd = _gcd(qs)
     if tick_ns is None:
-        tick = math.gcd(*pos) if pos else 1
+        tick = gcd or 1
     else:
         if tick_ns < 1:
             raise ValueError(f"tick_ns must be >= 1, got {tick_ns}")
         tick = int(tick_ns)
     # conservative horizon bound: any event time is a max-plus path sum
     # over distinct additive quantities <= their total sum
-    total_ns = sum(q for q in pos)
-    bound_ticks = total_ns // tick + len(qs) + 1
+    pos = qs[qs > 0]
+    total_ns = (sum(pos.tolist())
+                if pos.size and int(pos.max()) > _I64_MAX // pos.size
+                else int(pos.sum()))
+    bound_ticks = total_ns // tick + qs.size + 1
     if bound_ticks >= _INF:
         raise ej.TickRangeError(
             f"scenario horizon bound {total_ns} ns = {bound_ticks} "
             f"ticks at tick_ns={tick} >= 2**30 — exceeds the int32 "
             f"tick range; pass a coarser tick_ns= (tolerance tier) or "
             f"shrink the scenario")
-    exact = all(q % tick == 0 for q in pos)
+    exact = gcd % tick == 0         # tick divides every quantity
     tier = "exact" if exact else "tolerance"
-    tol = 0 if exact else tick * len(qs)
+    tol = 0 if exact else tick * qs.size
 
-    def q_add(x: int) -> int:           # additive quantity: round-half
-        return (int(x) + tick // 2) // tick
+    def q_add(x):                   # additive quantity: round-half
+        return (x + tick // 2) // tick
 
-    def q_ceil(x: int) -> int:          # threshold: exact under >= cmp
+    def q_ceil(x: int) -> int:      # threshold: exact under >= cmp
         return min(-(-int(x) // tick), _INF)
 
-    tapes, msgs = low["tapes"], low["msgs"]
-    n = len(tapes)
-    p = max(1, max((len(t) for t in tapes), default=0))
-    op_kind = np.zeros((n, p), np.int32)
-    op_arg = np.zeros((n, p), np.int32)
-    n_ops = np.zeros(n, np.int32)
-    for i, ops in enumerate(tapes):
-        n_ops[i] = len(ops)
-        for j, op in enumerate(ops):
-            if isinstance(op, VecCompute):
-                op_kind[i, j] = ej.OP_COMPUTE
-                op_arg[i, j] = q_add(op.ns)
-            elif isinstance(op, VecSend):
-                op_kind[i, j] = ej.OP_SEND
-                op_arg[i, j] = low["send_arg"][(i, j)]
-            else:
-                op_kind[i, j] = ej.OP_RECV
-                op_arg[i, j] = low["recv_arg"][(i, j)]
+    import jax.numpy as jnp
+    n = len(st["n_ops"])
+    op_arg = st["op_arg"].copy()
+    op_arg[st["comp_rows"], st["comp_cols"]] = q_add(low["comp_ns"])
     fail_pc = np.full(n, _INF, np.int32)
     fail_vt = np.full(n, _INF, np.int32)
-    for i in range(n):
-        if low["fail_pc"][i] is not None:
-            fail_pc[i] = low["fail_pc"][i]
-        if low["fail_vt"][i] is not None:
-            fail_vt[i] = q_ceil(low["fail_vt"][i])
-    s = len(low["scope_members"])
-    membership = np.zeros((n, s), bool)
-    skew = np.zeros(s, np.int32)
-    for j, members in enumerate(low["scope_members"]):
-        membership[members, j] = True
-        skew[j] = min(low["scope_skews"][j] // tick, _INF - 1)
-    m = len(msgs)
-    d = max((len(msg.extras) for msg in msgs), default=0)
-    ch1 = np.zeros(m, np.int32)
-    ser1 = np.zeros(m, np.int32)
-    lat1 = np.zeros(m, np.int32)
-    two = np.zeros(m, bool)
-    ch2 = np.zeros(m, np.int32)
-    ser2 = np.zeros(m, np.int32)
-    lat2 = np.zeros(m, np.int32)
+    for i, pc in low["fail_pc"].items():
+        fail_pc[i] = pc
+    for i, vt in low["fail_vt"].items():
+        fail_vt[i] = q_ceil(vt)
+    m = len(st["msgs"])
+    slot = np.zeros(m, np.int64)
+    for match, _, _ in low["degrades"]:
+        slot += match
+    d = int(slot.max(initial=0))
     extra = np.zeros((m, d), np.int32)
     extra_from = np.zeros((m, d), np.int32)
-    for i, msg in enumerate(msgs):
-        ch1[i], ser1[i], lat1[i] = msg.ch1, q_add(msg.ser1), \
-            q_add(msg.lat1)
-        two[i] = msg.two_stage
-        ch2[i], ser2[i], lat2[i] = msg.ch2, q_add(msg.ser2), \
-            q_add(msg.lat2)
-        for k, (frm, ext) in enumerate(msg.extras):
-            extra_from[i, k] = q_ceil(frm)
-            extra[i, k] = q_add(ext)
-    import jax.numpy as jnp
-    tape = ej.VecTape(
-        op_kind=jnp.asarray(op_kind), op_arg=jnp.asarray(op_arg),
-        n_ops=jnp.asarray(n_ops), fail_pc=jnp.asarray(fail_pc),
-        fail_vtime=jnp.asarray(fail_vt),
-        membership=jnp.asarray(membership), skew=jnp.asarray(skew),
-        send_overhead=jnp.int32(q_add(SEND_OVERHEAD_NS)),
-        msg_ch1=jnp.asarray(ch1), msg_ser1=jnp.asarray(ser1),
-        msg_lat1=jnp.asarray(lat1), msg_two_stage=jnp.asarray(two),
-        msg_ch2=jnp.asarray(ch2), msg_ser2=jnp.asarray(ser2),
-        msg_lat2=jnp.asarray(lat2), msg_extra=jnp.asarray(extra),
+    slot[:] = 0
+    for match, frm, ext in low["degrades"]:
+        rows = np.flatnonzero(match)
+        extra_from[rows, slot[rows]] = q_ceil(frm)
+        extra[rows, slot[rows]] = q_add(int(ext))
+        slot[rows] += 1
+    values = dict(
+        op_arg=jnp.asarray(op_arg), fail_pc=jnp.asarray(fail_pc),
+        fail_vtime=jnp.asarray(fail_vt), msg_extra=jnp.asarray(extra),
         msg_extra_from=jnp.asarray(extra_from))
-    total_ops = int(n_ops.sum())
+    if shared is not None and shared.structure is st \
+            and shared.tick_ns == tick:
+        tape = dataclasses.replace(shared.tape, **values)
+    else:
+        def ticks(x: np.ndarray):
+            return jnp.asarray(q_add(x).astype(np.int32))
+        skew = np.array([min(s // tick, _INF - 1)
+                         for s in st["scope_skews"]], np.int32)
+        tape = ej.VecTape(
+            op_kind=jnp.asarray(st["op_kind"]),
+            n_ops=jnp.asarray(st["n_ops"]),
+            membership=jnp.asarray(st["membership"]),
+            skew=jnp.asarray(skew),
+            send_overhead=jnp.int32(q_add(SEND_OVERHEAD_NS)),
+            msg_ch1=jnp.asarray(st["msg_ch1"].astype(np.int32)),
+            msg_ser1=ticks(st["msg_ser1"]), msg_lat1=ticks(st["msg_lat1"]),
+            msg_two_stage=jnp.asarray(st["msg_two_stage"]),
+            msg_ch2=jnp.asarray(st["msg_ch2"].astype(np.int32)),
+            msg_ser2=ticks(st["msg_ser2"]), msg_lat2=ticks(st["msg_lat2"]),
+            **values)
     return CompiledSim(
-        tape=tape, n_channels=low["n_channels"],
+        tape=tape, n_channels=st["n_channels"],
         tick_ns=tick, tier=tier, tol_ns=tol,
-        max_rounds=total_ops + n + 3,
-        n_tasks=n, n_programs=low["n_programs"],
-        task_names=low["task_names"], task_hosts=low["task_hosts"],
-        markers=low["markers"], msgs=msgs, hub_base=low["hub_base"],
-        n_hosts=low["n_hosts"], scenario_name=low["scenario_name"],
-        quantities=qs)
+        max_rounds=int(st["n_ops"].sum()) + n + 3,
+        n_tasks=n, n_programs=st["n_programs"],
+        task_names=st["task_names"], task_hosts=st["task_hosts"],
+        markers=st["markers"], msgs=st["msgs"], hub_base=st["hub_base"],
+        n_hosts=st["n_hosts"], scenario_name=low["scenario_name"],
+        quantities=qs, structure=st)
 
 
 def compile_simulation(sim, tick_ns: Optional[int] = None) -> CompiledSim:
@@ -805,6 +903,16 @@ class SweepResult:
     tier: str
 
 
+def _variant(sim, scenario: Scenario):
+    """``sim`` with another scenario: its topology, workloads and
+    placement, the objects themselves."""
+    from repro.sim.simulation import Simulation
+    return Simulation(sim.topology, sim.workloads, scenario,
+                      placement=sim.placement_spec, mode=sim.mode,
+                      capacity=sim.capacity, cpu_resource=sim.cpu_resource,
+                      cells=sim.cells_mode)
+
+
 def sweep_vectorized(sim, axis: List[Scenario], *,
                      tick_ns: Optional[int] = None,
                      max_rounds: Optional[int] = None) -> SweepResult:
@@ -813,36 +921,39 @@ def sweep_vectorized(sim, axis: List[Scenario], *,
     tapes).  Variants must share scenario *structure* (same tapes,
     messages, channels — injections may change durations, fail points,
     degrade extras); a shared tick (gcd across variants) keeps every
-    admissible variant on the exact tier.  Each returned report is
-    bit-identical to running its variant alone (asserted in tests)."""
+    admissible variant on the exact tier.  The first variant is lowered
+    and quantized in full; a later one whose resolved Interference list
+    equals the first's lowers only its injection values onto that
+    structure (its ``sim.lower`` span says ``path="shared"``, else
+    ``"full"``).  Each returned report is bit-identical to running its
+    variant alone (asserted in tests)."""
     import jax
     import jax.numpy as jnp
 
     if not axis:
         raise ValueError("sweep needs at least one Scenario")
-    from repro.sim.simulation import Simulation
     with obs.span("sim.sweep"):
         t0 = time.perf_counter()
         with obs.span("sim.variants"):
-            variants = [
-                Simulation(sim.topology, sim.workloads, sc,
-                           placement=sim.placement_spec, mode=sim.mode,
-                           capacity=sim.capacity,
-                           cpu_resource=sim.cpu_resource,
-                           cells=sim.cells_mode)
-                for sc in axis]
+            variants = [_variant(sim, sc) for sc in axis]
+        # the first variant's lowering is the structure the others share
+        # where they can (_shares_structure): they lower their values only
         lows = []
         for v in variants:
-            with obs.span("sim.lower"):
-                lows.append(_lower(v))
+            shared = lows[0] if lows and _shares_structure(v, lows[0]) \
+                else None
+            with obs.span("sim.lower",
+                          path="full" if shared is None else "shared"):
+                lows.append(_lower(v, shared=shared))
         if tick_ns is None:
             with obs.span("sim.quantize"):
-                pos = [q for low in lows for q in _quantities(low) if q > 0]
-                tick_ns = math.gcd(*pos) if pos else 1
+                tick_ns = math.gcd(*(_gcd(_quantities(low))
+                                     for low in lows)) or 1
         comps = []
         for low in lows:
             with obs.span("sim.quantize"):
-                comps.append(_quantize(low, tick_ns))
+                comps.append(_quantize(low, tick_ns,
+                                       shared=comps[0] if comps else None))
         base = comps[0]
         with obs.span("sim.stage"):
             shapes = [jax.tree_util.tree_map(lambda x: jnp.shape(x), c.tape)

@@ -4,6 +4,7 @@ as the benchmark's ``CompileCounter`` counts them, charged live spans
 that enclose exactly what the ledger charges, the vectorized engine's
 reports unchanged with spans recorded, and its timers covering whole
 calls."""
+import collections
 import contextlib
 import glob
 import importlib.util
@@ -163,6 +164,34 @@ def test_sweep_reports_unchanged_with_spans_recorded(tmp_path):
         da, db = a.to_dict(), b.to_dict()
         da["wall_s"] = db["wall_s"] = 0.0
         assert da == db
+
+
+def test_sweep_lowers_each_variant_once_and_marks_its_path(tmp_path,
+                                                          monkeypatch):
+    """Wrapped by name as the benchmark wraps them, ``_lower`` and
+    ``_quantize`` run once per variant, and every variant after the
+    first lowers on the first one's structure: its ``sim.lower`` span
+    says ``path="shared"``."""
+    calls = collections.Counter()
+
+    def counting(name):
+        fn = getattr(vectorized, name)
+
+        def inner(*args, **kw):
+            calls[name] += 1
+            return fn(*args, **kw)
+        return inner
+    for name in ("_lower", "_quantize"):
+        monkeypatch.setattr(vectorized, name, counting(name))
+    axis = [Scenario(f"s{i}", (Straggler(f"w{i % 4}", 1.0 + i / 4),))
+            for i in range(5)]
+    pdata = []
+    with profiled(tmp_path, pdata):
+        rack_sim().sweep(axis)
+    assert calls == {"_lower": len(axis), "_quantize": len(axis)}
+    spans = host_events(pdata[0], "livestack.sim.lower")
+    assert [dict(e.stats)["path"] for e in spans] \
+        == ["full"] + ["shared"] * (len(axis) - 1)
 
 
 def slowed(fn, s):

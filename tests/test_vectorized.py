@@ -1,8 +1,11 @@
 """Vectorized-engine conformance: the two-tier contract of
 ``Simulation.run(engine="vectorized")`` (see tests/engine_harness.py),
 the ``UnsupportedByEngine`` surface, the int32 tick-range guard, and
-the ``Simulation.sweep`` vmap batch.
+the ``Simulation.sweep`` vmap batch and its shared structure.
 """
+import dataclasses
+import math
+
 import numpy as np
 import pytest
 
@@ -11,10 +14,12 @@ from engine_harness import (assert_vectorized_exact,
                             assert_engines_agree, run_engine)
 from repro.core.cluster import ClusterSpec, StepCost
 from repro.core.engine_jax import INF_TICKS
-from repro.sim import (ChipRingTraining, DegradeLink, FailHost,
-                       FailTask, Interference, ModeledServe, RackRing,
-                       Scenario, Simulation, Straggler, TickRangeError,
-                       Topology, UnsupportedByEngine)
+from repro.sim import (BitFlip, ChipRingTraining, ClockSkew, DegradeLink,
+                       FailHost, FailTask, Interference, JoinHost,
+                       ModeledServe, RackRing, Scenario, Simulation,
+                       Straggler, TickRangeError, Topology,
+                       UnsupportedByEngine)
+from repro.sim import vectorized
 
 
 def rack_sim(sc=None, *, n_iters=12, skew=100_000, compute=5_000):
@@ -326,6 +331,131 @@ class TestSweep:
     def test_sweep_empty_axis(self):
         with pytest.raises(ValueError):
             rack_sim()().sweep([])
+
+
+# --------------------------------------------------------------------------
+# a sweep's shared structure: later variants lower only their values
+# --------------------------------------------------------------------------
+
+#: per fleet: its factory, a task, another task, a host pair, a fabric,
+#: a host and a vtime inside the run
+FLEETS = {
+    "rack": (rack_sim, ("w1", "w2", (0, 2), "hub", 1, 160_000)),
+    "chip": (chip_sim, ("chip5", "chip2", (0, 1), "dcn", 1, 120_000)),
+}
+
+#: per injection kind: (the base scenario's injections, the variant's)
+SHARED_CASES = {
+    "none": lambda t, u, hosts, fab, host, vt: ((), ()),
+    "straggler": lambda t, u, hosts, fab, host, vt: (
+        (), (Straggler(t, 2.5),)),
+    "two_stragglers": lambda t, u, hosts, fab, host, vt: (
+        (), (Straggler(t, 2.5), Straggler(t, 1.5))),
+    "fail_at_compute": lambda t, u, hosts, fab, host, vt: (
+        (), (FailTask(t, at_compute=2),)),
+    "fail_at_vtime": lambda t, u, hosts, fab, host, vt: (
+        (), (FailTask(t, at_vtime=vt),)),
+    "fail_host": lambda t, u, hosts, fab, host, vt: (
+        (), (FailHost(host, at_vtime=vt),)),
+    "degrade_fabric": lambda t, u, hosts, fab, host, vt: (
+        (), (DegradeLink(fabric=fab, latency_factor=3.0),)),
+    "degrade_hosts": lambda t, u, hosts, fab, host, vt: (
+        (), (DegradeLink(hosts=hosts, extra_ns=7_000, from_vtime=50_000),)),
+    # the base's own straggler must not leak into the shared compute ns
+    "base_straggler": lambda t, u, hosts, fab, host, vt: (
+        (Straggler(t, 3.0),), (Straggler(u, 1.25),)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHARED_CASES))
+@pytest.mark.parametrize("fleet", sorted(FLEETS))
+def test_shared_structure_equals_full_lowering(fleet, case):
+    """A variant lowered on the first variant's structure compiles to
+    exactly what its own full lowering compiles to: every tape array,
+    the tick (shared and solo), tier, bound, round cap and quantities."""
+    make, args = FLEETS[fleet]
+    base, injections = SHARED_CASES[case](*args)
+    sim0 = make(Scenario("base", base))()
+    low0 = vectorized._lower(sim0)
+    sc = Scenario(case, injections)
+    shared_sim = vectorized._variant(sim0, sc)
+    full_sim = vectorized._variant(sim0, sc)
+    low = vectorized._lower(shared_sim, shared=low0)
+    full = vectorized._lower(full_sim)
+    assert low["structure"] is low0["structure"]
+    assert full["structure"] is not low0["structure"]
+    assert shared_sim.placement == full_sim.placement
+    tick = math.gcd(*(vectorized._gcd(vectorized._quantities(x))
+                      for x in (low0, low)))
+    comp0 = vectorized._quantize(low0, tick)
+    for got, want in (
+            (vectorized._quantize(low, tick, shared=comp0),
+             vectorized._quantize(full, tick)),
+            (vectorized._quantize(low, None),
+             vectorized._quantize(full, None))):
+        for f in dataclasses.fields(want.tape):
+            a = np.asarray(getattr(got.tape, f.name))
+            b = np.asarray(getattr(want.tape, f.name))
+            assert a.dtype == b.dtype and np.array_equal(a, b), f.name
+        for attr in ("tick_ns", "tier", "tol_ns", "max_rounds",
+                     "n_channels", "n_tasks", "task_names", "task_hosts",
+                     "markers", "scenario_name"):
+            assert getattr(got, attr) == getattr(want, attr), attr
+        assert np.array_equal(got.quantities, want.quantities)
+    # at the shared tick the structural arrays are the first variant's
+    got = vectorized._quantize(low, tick, shared=comp0)
+    assert got.tape.op_kind is comp0.tape.op_kind
+
+
+def lowered_paths(monkeypatch):
+    """Record, per ``_lower`` call, whether it was handed a structure."""
+    seen = []
+    lower = vectorized._lower
+
+    def spy(sim, shared=None):
+        seen.append(shared is not None)
+        return lower(sim, shared=shared)
+    monkeypatch.setattr(vectorized, "_lower", spy)
+    return seen
+
+
+@pytest.mark.parametrize("injections, error, match, shared", [
+    ((BitFlip("w1", at_step=0),), UnsupportedByEngine, "BitFlip", True),
+    ((ClockSkew(host=1, offset_ns=100),), UnsupportedByEngine,
+     "ClockSkew", True),
+    ((JoinHost(3, at_vtime=1_000),), UnsupportedByEngine, "joins", True),
+    ((Straggler("nope", 2.0),), ValueError, "unknown", True),
+    ((FailTask("w0", at_compute=1), FailTask("w0", at_compute=2)),
+     ValueError, "two failures", True),
+    ((FailHost(99, at_vtime=1_000),), ValueError, "FailHost", True),
+    ((DegradeLink(),), ValueError, "exactly one", True),
+    ((DegradeLink(hosts=(0, 1), latency_factor=0.1),), ValueError,
+     "only add", True),
+    ((Interference(host=0, bursts=3, burst_ns=1_000),),
+     UnsupportedByEngine, "structure", False),
+], ids=["bitflip", "clockskew", "joinhost", "unknown_straggler",
+        "two_failtasks", "failhost_range", "degrade_neither",
+        "degrade_negative", "interference"])
+def test_sweep_variant_checks_on_its_path(monkeypatch, injections, error,
+                                          match, shared):
+    """A later variant is checked on the shared path as a full lowering
+    checks it; one with another Interference list takes the full path
+    and is refused by the structure check."""
+    seen = lowered_paths(monkeypatch)
+    axis = [Scenario("base"), Scenario("bad", injections)]
+    with pytest.raises(error, match=match):
+        rack_sim()().sweep(axis)
+    assert seen == [False, shared]
+
+
+def test_sweep_refuses_straggled_zero_progress(monkeypatch):
+    """A straggler that leaves a tape with PREEMPT_AFTER zero-progress
+    computes is refused on the shared path too."""
+    seen = lowered_paths(monkeypatch)
+    axis = [Scenario("base"), Scenario("z", (Straggler("w2", 0.0),))]
+    with pytest.raises(UnsupportedByEngine, match="zero-progress"):
+        rack_sim(n_iters=vectorized.PREEMPT_AFTER)().sweep(axis)
+    assert seen == [False, True]
 
 
 # --------------------------------------------------------------------------
